@@ -26,6 +26,8 @@ def main() -> None:
                     help="trace seeds per grid cell; >1 adds mean±std "
                          "error bars to fig1/fig2")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.profile:
         os.environ["REPRO_BENCH_PROFILE"] = args.profile
     if args.seeds:

@@ -64,45 +64,49 @@ def make_state(num_sets: int, associativity: int, *, filter_bytes: int = 32) -> 
     )
 
 
-def _hash_bits(tag: jnp.ndarray, num_bits: int) -> jnp.ndarray:
-    """Return the NUM_HASHES bit positions (int32, < num_bits) for ``tag``.
+def _hash_bits(tag: jnp.ndarray, num_bits: int) -> Tuple[jnp.ndarray, ...]:
+    """Return the NUM_HASHES bit positions (int32, < num_bits) for ``tag``,
+    one array of ``tag``'s shape per hash.
 
     Unrolled over the (static, tiny) multiplier list with scalar constants
-    only — no captured constant vectors — so the same code is traceable
-    both under jit/vmap and inside the engine's Pallas kernel bodies.
+    only — no captured constant vectors and no stacking — so the same code
+    is traceable both under jit/vmap and inside Pallas kernel bodies.
     """
     tag = tag.astype(jnp.uint32)
-    hs = []
+    out = []
     for m in _HASH_MULTIPLIERS[:NUM_HASHES]:
         # multiply-shift: high bits of tag * odd constant are well mixed
         hm = tag * jnp.uint32(m)
-        hs.append(hm ^ (hm >> jnp.uint32(15)))
-    h = jnp.stack(hs, axis=-1)
-    return (h % jnp.uint32(num_bits)).astype(jnp.int32)
+        h = hm ^ (hm >> jnp.uint32(15))
+        out.append((h % jnp.uint32(num_bits)).astype(jnp.int32))
+    return tuple(out)
 
 
-def _bit_mask(bits: jnp.ndarray, words: int) -> jnp.ndarray:
-    """Expand bit positions (k,) into a (words,) uint32 OR-mask."""
-    word_idx = bits // 32
-    bit_idx = (bits % 32).astype(jnp.uint32)
-    one = jnp.uint32(1)
-    masks = jnp.zeros((words,), dtype=jnp.uint32)
-    # k is tiny and static — unrolled updates
-    for i in range(bits.shape[-1]):
-        masks = masks.at[word_idx[..., i]].set(
-            masks[word_idx[..., i]] | (one << bit_idx[..., i])
-        )
+def _bit_mask(bits: Tuple[jnp.ndarray, ...], shape) -> jnp.ndarray:
+    """Expand bit positions into a uint32 OR-mask of ``shape`` (words on
+    axis 0)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    masks = jnp.zeros(shape, jnp.uint32)
+    for b in bits:
+        one = jnp.uint32(1) << (b & 31).astype(jnp.uint32)
+        masks = masks | jnp.where(iota == (b >> 5), one, jnp.uint32(0))
     return masks
 
 
-def _test(filter_words: jnp.ndarray, bits: jnp.ndarray) -> jnp.ndarray:
-    """True iff all hash bits are set in the filter (possible membership)."""
-    word_idx = bits // 32
-    bit_idx = (bits % 32).astype(jnp.uint32)
-    present = jnp.bool_(True)
-    for i in range(bits.shape[-1]):
-        w = filter_words[word_idx[..., i]]
-        present = present & (((w >> bit_idx[..., i]) & jnp.uint32(1)) == 1)
+def _test(filter_words: jnp.ndarray, bits: Tuple[jnp.ndarray, ...]
+          ) -> jnp.ndarray:
+    """True iff all hash bits are set in the filter (possible membership).
+    Words run along axis 0: a (words, N) filter gives a (1, N) answer, a
+    (words,) filter a scalar.  The word holding each bit is picked with a
+    one-hot over the words and an int32 max (the form Mosaic lowers)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, filter_words.shape, 0)
+    present = None
+    for b in bits:
+        bit = (b & 31).astype(jnp.uint32)
+        set_ = (iota == (b >> 5)) & (((filter_words >> bit) & 1) == 1)
+        has = jnp.max(set_.astype(jnp.int32), axis=0,
+                      keepdims=filter_words.ndim > 1) > 0
+        present = has if present is None else present & has
     return present
 
 
@@ -130,7 +134,7 @@ def record_access(state: BloomPredictorState, set_idx: jnp.ndarray, tag: jnp.nda
     already in BF2; swap when ``n >= associativity`` (8)-(9)."""
     words = state.bf1.shape[1]
     bits = _hash_bits(tag, words * 32)
-    mask = _bit_mask(bits, words)
+    mask = _bit_mask(bits, (words,))
 
     bf1_row = jax.lax.dynamic_index_in_dim(state.bf1, set_idx, 0, keepdims=False)
     bf2_row = jax.lax.dynamic_index_in_dim(state.bf2, set_idx, 0, keepdims=False)
@@ -198,10 +202,9 @@ def _counting_cells(tag: jnp.ndarray, cells: int) -> jnp.ndarray:
 
 def counting_insert(st: CountingBloomState, set_idx, tag) -> CountingBloomState:
     row = st.counters[set_idx]
-    idx = _counting_cells(tag, row.shape[-1])
-    for i in range(idx.shape[-1]):
-        c = row[idx[i]]
-        row = row.at[idx[i]].set(jnp.minimum(c + 1, 15).astype(jnp.uint8))
+    for i in _counting_cells(tag, row.shape[-1]):
+        c = row[i]
+        row = row.at[i].set(jnp.minimum(c + 1, 15).astype(jnp.uint8))
     return st._replace(counters=st.counters.at[set_idx].set(row))
 
 
@@ -210,18 +213,16 @@ def counting_remove(st: CountingBloomState, set_idx, tag) -> CountingBloomState:
     Saturated counters (15) are sticky: decrementing them could create
     false negatives, so they stay (a standard counting-BF rule)."""
     row = st.counters[set_idx]
-    idx = _counting_cells(tag, row.shape[-1])
-    for i in range(idx.shape[-1]):
-        c = row[idx[i]]
+    for i in _counting_cells(tag, row.shape[-1]):
+        c = row[i]
         dec = jnp.where((c > 0) & (c < 15), c - 1, c)
-        row = row.at[idx[i]].set(dec.astype(jnp.uint8))
+        row = row.at[i].set(dec.astype(jnp.uint8))
     return st._replace(counters=st.counters.at[set_idx].set(row))
 
 
 def counting_query(st: CountingBloomState, set_idx, tag) -> jnp.ndarray:
     row = st.counters[set_idx]
-    idx = _counting_cells(tag, row.shape[-1])
     hit = jnp.bool_(True)
-    for i in range(idx.shape[-1]):
-        hit &= row[idx[i]] > 0
+    for i in _counting_cells(tag, row.shape[-1]):
+        hit &= row[i] > 0
     return hit

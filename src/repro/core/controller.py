@@ -38,7 +38,7 @@ from . import address_separation as asep
 from . import bloom as bloomlib
 from .compression import BLOCK_BYTES, HIGH, LOW
 from .energy import PaperGPU
-from .tag_store import LRU_MAX
+from .tag_store import LRU_MAX_INT
 
 
 class Predictor(enum.Enum):
@@ -177,13 +177,20 @@ def _upd(a, row, i):
 # All mutable simulator state is keyed by (tier, set) and every request
 # touches exactly one set, so the whole simulation decomposes into
 # independent per-set state machines.  These kernels are that decomposition:
-# each maps (one set's state rows, one request) -> (new rows, outcome).
-# ``step`` (the serial oracle) applies them at a dynamically-indexed row;
-# ``core.engine`` vmaps them over all sets at once.
+# each maps (sets' state, one request per set) -> (new state, outcome).
+#
+# Layout: one COLUMN per set.  Way-indexed leaves are (ways, N), Bloom
+# words (words, N), per-set scalars and the request fields (1, N).  Every
+# reduction runs over axis 0 with keepdims, so all values stay 2-D with
+# sets on the lane axis — the form Mosaic lowers on TPU.  The same code
+# serves all three engines: ``step`` (the serial oracle) passes one set
+# (N = 1), the jnp engine and the Pallas kernel pass every set of a trace.
+# Only int32/bool reductions are used (Mosaic has no unsigned reductions or
+# unsigned max), so uint32 LRU counters are decremented as int32.
 # ---------------------------------------------------------------------------
 
 class ConvRow(NamedTuple):
-    """One conventional-LLC set: (ways,) metadata vectors."""
+    """Conventional-LLC sets: (ways, N) metadata."""
     tags: jnp.ndarray     # uint32
     valid: jnp.ndarray    # bool
     dirty: jnp.ndarray    # bool
@@ -191,16 +198,16 @@ class ConvRow(NamedTuple):
 
 
 class ExtRow(NamedTuple):
-    """One extended-LLC set: (ext_max_ways,) metadata + predictor filters."""
+    """Extended-LLC sets: (ext_max_ways, N) metadata + predictor filters."""
     tags: jnp.ndarray
     valid: jnp.ndarray
     dirty: jnp.ndarray
     lru: jnp.ndarray
     size: jnp.ndarray     # int32 physical bytes per block
-    used: jnp.ndarray     # () int32
-    bf1: jnp.ndarray      # (words,) uint32
+    used: jnp.ndarray     # (1, N) int32
+    bf1: jnp.ndarray      # (words, N) uint32
     bf2: jnp.ndarray
-    n_mru: jnp.ndarray    # () int32
+    n_mru: jnp.ndarray    # (1, N) int32
 
 
 class ConvOutcome(NamedTuple):
@@ -215,46 +222,54 @@ class ExtOutcome(NamedTuple):
     swap: jnp.ndarray      # bool — Bloom filters swapped this access
 
 
-def conv_row_zero(cfg: MorpheusConfig) -> ConvRow:
-    w = cfg.conv_ways
-    return ConvRow(tags=jnp.zeros((w,), jnp.uint32),
-                   valid=jnp.zeros((w,), jnp.bool_),
-                   dirty=jnp.zeros((w,), jnp.bool_),
-                   lru=jnp.zeros((w,), jnp.uint32))
+def _any(mask: jnp.ndarray) -> jnp.ndarray:
+    """OR over the ways axis, as an int32 max (1, N)."""
+    return jnp.max(mask.astype(jnp.int32), axis=0, keepdims=True) > 0
 
 
-def ext_row_zero(cfg: MorpheusConfig, words: int = BLOOM_WORDS) -> ExtRow:
-    w = cfg.ext_max_ways
-    return ExtRow(tags=jnp.zeros((w,), jnp.uint32),
-                  valid=jnp.zeros((w,), jnp.bool_),
-                  dirty=jnp.zeros((w,), jnp.bool_),
-                  lru=jnp.zeros((w,), jnp.uint32),
-                  size=jnp.zeros((w,), jnp.int32),
-                  used=jnp.zeros((), jnp.int32),
-                  bf1=jnp.zeros((words,), jnp.uint32),
-                  bf2=jnp.zeros((words,), jnp.uint32),
-                  n_mru=jnp.zeros((), jnp.int32))
+def _first(mask: jnp.ndarray) -> jnp.ndarray:
+    """First True way per column, 0 when there is none (= ``argmax``)."""
+    w = mask.shape[0]
+    iota = jax.lax.broadcasted_iota(jnp.int32, mask.shape, 0)
+    first = jnp.min(jnp.where(mask, iota, w), axis=0, keepdims=True)
+    return jnp.where(first == w, 0, first)
+
+
+def _argmin(key: jnp.ndarray) -> jnp.ndarray:
+    """First minimal way per column of an int32 key (= ``argmin``)."""
+    return _first(key == jnp.min(key, axis=0, keepdims=True))
+
+
+def _sel(c: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """``jnp.where`` that Mosaic lowers for bool operands too (it has no
+    select over bool vectors, so those become and/or)."""
+    if jnp.result_type(x) == jnp.bool_:
+        return (c & x) | (~c & y)
+    return jnp.where(c, x, y)
+
+
+def _dec(lru: jnp.ndarray) -> jnp.ndarray:
+    """Saturating LRU decrement, in int32."""
+    return jnp.maximum(lru, 1) - 1
 
 
 def conv_set_kernel(cfg: MorpheusConfig, row: ConvRow, tag: jnp.ndarray,
                     is_write: jnp.ndarray) -> Tuple[ConvRow, ConvOutcome]:
-    """LRU lookup/insert on one conventional set (Algorithm-1 metadata)."""
+    """LRU lookup/insert on conventional sets (Algorithm-1 metadata).
+    ``tag`` uint32 and ``is_write`` bool are (1, N)."""
     ctags, cvalid, cdirty, clru = row
-    is_write = jnp.bool_(is_write)
+    lru = clru.astype(jnp.int32)
+    iota = jax.lax.broadcasted_iota(jnp.int32, ctags.shape, 0)
     cmatch = cvalid & (ctags == tag)
-    c_hit = jnp.any(cmatch)
-    way_hit = jnp.argmax(cmatch).astype(jnp.int32)
-    vkey = jnp.where(cvalid, clru.astype(jnp.int32), -1)
-    way_vic = jnp.argmin(vkey).astype(jnp.int32)
-    way = jnp.where(c_hit, way_hit, way_vic)
-    onehot = jnp.arange(ctags.shape[0], dtype=jnp.int32) == way
-    c_evict_wb = ~c_hit & cvalid[way_vic] & cdirty[way_vic]
+    c_hit = _any(cmatch)
+    way_vic = _argmin(jnp.where(cvalid, lru, -1))
+    way = jnp.where(c_hit, _first(cmatch), way_vic)
+    onehot = iota == way
+    c_evict_wb = ~c_hit & _any((iota == way_vic) & cvalid & cdirty)
     n_ctags = jnp.where(onehot & ~c_hit, tag, ctags)
     n_cvalid = cvalid | (onehot & ~c_hit)
-    n_cdirty = jnp.where(onehot, jnp.where(c_hit, cdirty | is_write, is_write),
-                         cdirty)
-    n_clru = jnp.where(onehot, LRU_MAX,
-                       jnp.maximum(clru, 1) - 1).astype(jnp.uint32)
+    n_cdirty = _sel(onehot, (c_hit & cdirty) | is_write, cdirty)
+    n_clru = jnp.where(onehot, LRU_MAX_INT, _dec(lru)).astype(clru.dtype)
     return (ConvRow(n_ctags, n_cvalid, n_cdirty, n_clru),
             ConvOutcome(c_hit, c_evict_wb))
 
@@ -262,89 +277,105 @@ def conv_set_kernel(cfg: MorpheusConfig, row: ConvRow, tag: jnp.ndarray,
 def ext_set_kernel(cfg: MorpheusConfig, row: ExtRow, tag: jnp.ndarray,
                    is_write: jnp.ndarray, level: jnp.ndarray
                    ) -> Tuple[ExtRow, ExtOutcome]:
-    """Predict -> lookup -> touch/insert on one extended set (§4.1-§4.3)."""
-    etags, evalid, edirty, elru = row.tags, row.valid, row.dirty, row.lru
+    """Predict -> lookup -> touch/insert on extended sets (§4.1-§4.3).
+    ``tag`` uint32, ``is_write`` bool and ``level`` int32 are (1, N)."""
+    etags, evalid, edirty = row.tags, row.valid, row.dirty
     esize, eused = row.size, row.used
     bf1, bf2, n = row.bf1, row.bf2, row.n_mru
-    is_write = jnp.bool_(is_write)
+    lru = row.lru.astype(jnp.int32)
+    iota = jax.lax.broadcasted_iota(jnp.int32, etags.shape, 0)
 
     ematch = evalid & (etags == tag)
-    e_hit = jnp.any(ematch)
-    e_way = jnp.argmax(ematch).astype(jnp.int32)
+    e_hit = _any(ematch)
+    e_way = _first(ematch)
 
-    words = bf1.shape[0]
-    bits = bloomlib._hash_bits(tag, words * 32)
+    bits = bloomlib._hash_bits(tag, bf1.shape[0] * 32)
     if cfg.predictor is Predictor.BLOOM:
         pred = bloomlib._test(bf1, bits)
     elif cfg.predictor is Predictor.PERFECT:
         pred = e_hit
     else:
-        pred = jnp.bool_(True)
+        pred = jnp.ones_like(e_hit)
 
-    phys = jnp.where(~jnp.bool_(cfg.compression), BLOCK_BYTES,
-                     jnp.where(level == HIGH, 32,
-                               jnp.where(level == LOW, 64, BLOCK_BYTES))
-                     ).astype(jnp.int32)
+    if cfg.compression:
+        phys = jnp.where(level == HIGH, 32,
+                         jnp.where(level == LOW, 64, BLOCK_BYTES))
+    else:
+        phys = jnp.full(level.shape, BLOCK_BYTES, jnp.int32)
 
     # touch path (hit): Algorithm 1 lines 8-12
-    eidx = jnp.arange(etags.shape[0], dtype=jnp.int32)
-    t_onehot = eidx == e_way
-    t_lru = jnp.where(t_onehot, LRU_MAX, jnp.maximum(elru, 1) - 1
-                      ).astype(jnp.uint32)
+    t_onehot = iota == e_way
+    t_lru = jnp.where(t_onehot, LRU_MAX_INT, _dec(lru))
     t_dirty = edirty | (t_onehot & is_write)
 
     # insert path (miss): LRU-evict until the block fits (≤4 evictions)
-    i_tags, i_valid, i_dirty = etags, evalid, edirty
-    i_lru, i_size, i_used = elru, esize, eused
-    wbs = jnp.int32(0)
+    i_valid, i_dirty, i_size, i_used = evalid, edirty, esize, eused
+    wbs = jnp.zeros_like(eused)
     budget = cfg.ext_budget_bytes
     for _ in range(BLOCK_BYTES // 32):
         need = (i_used + phys) > budget
-        key = jnp.where(i_valid, i_lru.astype(jnp.int32),
-                        jnp.int32(LRU_MAX) + 1)
-        v = jnp.argmin(key).astype(jnp.int32)
-        can = need & jnp.any(i_valid)
-        oh = eidx == v
-        wbs += (can & i_dirty[v]).astype(jnp.int32)
-        i_used = jnp.where(can, i_used - i_size[v], i_used)
-        i_valid = jnp.where(can & oh, False, i_valid)
-        i_dirty = jnp.where(can & oh, False, i_dirty)
-        i_size = jnp.where(can & oh, 0, i_size)
-    free_way = jnp.argmax(~i_valid).astype(jnp.int32)
-    oh = eidx == free_way
-    i_tags = jnp.where(oh, tag, i_tags)
+        v = _argmin(jnp.where(i_valid, lru, LRU_MAX_INT + 1))
+        can = need & _any(i_valid)
+        oh = iota == v
+        gone = can & oh
+        wbs += (can & _any(oh & i_dirty)).astype(jnp.int32)
+        i_used = i_used - jnp.sum(jnp.where(gone, i_size, 0), axis=0,
+                                  keepdims=True)
+        i_valid = i_valid & ~gone
+        i_dirty = i_dirty & ~gone
+        i_size = jnp.where(gone, 0, i_size)
+    oh = iota == _first(~i_valid)
+    i_tags = jnp.where(oh, tag, etags)
     i_valid = i_valid | oh
-    i_dirty = jnp.where(oh, is_write, i_dirty)
+    i_dirty = _sel(oh, is_write, i_dirty)
     i_size = jnp.where(oh, phys, i_size)
-    i_lru = jnp.where(oh, LRU_MAX, jnp.maximum(i_lru, 1) - 1).astype(jnp.uint32)
+    i_lru = jnp.where(oh, LRU_MAX_INT, _dec(lru))
     i_used = i_used + phys
 
     # merge: hit -> touch rows; miss -> insert rows
     n_etags = jnp.where(e_hit, etags, i_tags)
-    n_evalid = jnp.where(e_hit, evalid, i_valid)
-    n_edirty = jnp.where(e_hit, t_dirty, i_dirty)
-    n_elru = jnp.where(e_hit, t_lru, i_lru)
+    n_evalid = _sel(e_hit, evalid, i_valid)
+    n_edirty = _sel(e_hit, t_dirty, i_dirty)
+    n_elru = jnp.where(e_hit, t_lru, i_lru).astype(row.lru.dtype)
     n_esize = jnp.where(e_hit, esize, i_size)
     n_eused = jnp.where(e_hit, eused, i_used)
 
     # Bloom maintenance (Fig. 6(b)): every ext access inserts into both
     # filters; n += (tag not already in BF2); swap at n >= associativity.
     if cfg.predictor is Predictor.BLOOM:
-        mask = bloomlib._bit_mask(bits, words)
+        mask = bloomlib._bit_mask(bits, bf1.shape)
         was_in_bf2 = bloomlib._test(bf2, bits)
         u_bf1, u_bf2 = bf1 | mask, bf2 | mask
-        u_n = n + jnp.where(was_in_bf2, 0, 1).astype(jnp.int32)
+        u_n = n + jnp.where(was_in_bf2, 0, 1)
         do_swap = u_n >= cfg.ext_ways    # logical associativity
         n_bf1 = jnp.where(do_swap, u_bf2, u_bf1)
         n_bf2 = jnp.where(do_swap, jnp.zeros_like(u_bf2), u_bf2)
         u_n = jnp.where(do_swap, 0, u_n)
     else:
         n_bf1, n_bf2, u_n = bf1, bf2, n
-        do_swap = jnp.bool_(False)
+        do_swap = jnp.zeros_like(e_hit)
 
     return (ExtRow(n_etags, n_evalid, n_edirty, n_elru, n_esize, n_eused,
                    n_bf1, n_bf2, u_n),
             ExtOutcome(e_hit, pred, wbs, do_swap))
+
+
+def conv_slot(cfg: MorpheusConfig, row: ConvRow, tag, is_write, active,
+              counted) -> Tuple[ConvRow, "Stats"]:
+    """One request slot of every conventional set: transition the sets
+    whose slot is ``active`` (padding holds state) and return the Stats
+    delta of the ``counted`` ones.  Request fields are (1, N)."""
+    new_row, out = conv_set_kernel(cfg, row, tag, is_write)
+    row = jax.tree.map(lambda nn, oo: _sel(active, nn, oo), new_row, row)
+    return row, request_stats(cfg, counted, out, np.bool_(False), _NO_EXT)
+
+
+def ext_slot(cfg: MorpheusConfig, row: ExtRow, tag, is_write, level, active,
+             counted) -> Tuple[ExtRow, "Stats"]:
+    """``conv_slot`` for the extended tier."""
+    new_row, out = ext_set_kernel(cfg, row, tag, is_write, level)
+    row = jax.tree.map(lambda nn, oo: _sel(active, nn, oo), new_row, row)
+    return row, request_stats(cfg, np.bool_(False), _NO_CONV, counted, out)
 
 
 def request_stats(cfg: MorpheusConfig, sel_c: jnp.ndarray,
@@ -388,7 +419,7 @@ def request_stats(cfg: MorpheusConfig, sel_c: jnp.ndarray,
     noc = (i1(ext_hit_e | ext_fp) + i1(is_ext & ~e_hit)
            + jnp.where(is_ext & ~e_hit, wbs, 0)) * BLOCK_BYTES
 
-    use_bloom = is_ext & jnp.bool_(cfg.predictor is Predictor.BLOOM)
+    use_bloom = is_ext & np.bool_(cfg.predictor is Predictor.BLOOM)
     return Stats(
         conv_hits=i1(conv_hit_e),
         conv_misses=i1(conv_miss_e),
@@ -414,6 +445,15 @@ _NO_EXT = ExtOutcome(hit=np.bool_(False), pred=np.bool_(False),
                      wbs=np.int32(0), swap=np.bool_(False))
 
 
+def _one_set(kernel, cfg: MorpheusConfig, row, *req):
+    """Apply a column kernel to ONE set: (ways,) rows and scalar request
+    fields travel as a single (ways, 1) / (1, 1) column."""
+    col = lambda x: jnp.reshape(x, (-1, 1))
+    new, out = kernel(cfg, jax.tree.map(col, row), *map(col, req))
+    return (jax.tree.map(lambda nn, oo: jnp.reshape(nn, oo.shape), new, row),
+            jax.tree.map(lambda x: jnp.reshape(x, ()), out))
+
+
 def step(cfg: MorpheusConfig, st: MorpheusState,
          addr: jnp.ndarray, is_write: jnp.ndarray, level: jnp.ndarray
          ) -> MorpheusState:
@@ -429,11 +469,12 @@ def step(cfg: MorpheusConfig, st: MorpheusState,
     conv_set = jnp.where(is_ext, 0, local_set)
     ext_set = jnp.where(is_ext, local_set, 0)
     sel_c = ~is_ext
+    is_write = jnp.asarray(is_write, jnp.bool_)
 
     # ----- conventional LLC row update (identity when routed extended) -----
     crow = ConvRow(_idx(st.conv_tags, conv_set), _idx(st.conv_valid, conv_set),
                    _idx(st.conv_dirty, conv_set), _idx(st.conv_lru, conv_set))
-    n_crow, c_out = conv_set_kernel(cfg, crow, tag, is_write)
+    n_crow, c_out = _one_set(conv_set_kernel, cfg, crow, tag, is_write)
     st = st._replace(
         conv_tags=_upd(st.conv_tags, jnp.where(sel_c, n_crow.tags, crow.tags),
                        conv_set),
@@ -451,7 +492,8 @@ def step(cfg: MorpheusConfig, st: MorpheusState,
                   _idx(st.ext_size, ext_set), _idx(st.ext_used, ext_set),
                   _idx(st.bf1, ext_set), _idx(st.bf2, ext_set),
                   _idx(st.n_mru, ext_set))
-    n_erow, e_out = ext_set_kernel(cfg, erow, tag, is_write, level)
+    n_erow, e_out = _one_set(ext_set_kernel, cfg, erow, tag, is_write,
+                             level)
     st = st._replace(
         ext_tags=_upd(st.ext_tags, jnp.where(is_ext, n_erow.tags, erow.tags),
                       ext_set),

@@ -13,10 +13,12 @@ This module exploits that:
      sort, so the in-set request order (the only order that matters) is
      preserved — and lays the per-set subsequences out as padded dense
      (num_sets, L) arrays with an activity mask.
-  2. ``_run_packed`` scans each set's subsequence with the pure per-set
+  2. ``_run_packed_state`` scans the packed slots with the pure per-set
      kernels from ``controller`` (the same code the serial oracle runs),
-     ``vmap``-ed over all sets, and over a batch of traces; per-request
-     Stats deltas are accumulated in the scan carry and reduced over sets.
+     each step transitioning every set of a trace at once (one column per
+     set), ``vmap``-ed over a batch of traces; per-request Stats deltas
+     are accumulated in the scan carry and reduced over sets.
+     ``_run_packed`` is the same from cold caches.
   3. ``simulate_parallel`` / ``simulate_batch`` are the public entry
      points.  Integer counters are *exactly* equal to the serial scan's
      (same kernels, same in-set order); float sums differ only by
@@ -34,10 +36,10 @@ Backends: the inner per-set scan has two interchangeable implementations,
 selected by ``backend`` on every public entry point (and threaded through
 ``cache_sim.RunPoint``/``run_batch``, ``policy`` and the benchmarks):
 
-  * ``"jnp"``    — the pure-jnp vmap-over-sets scan below (CPU default);
-  * ``"pallas"`` — the fused per-set Pallas kernel in
-    ``kernels/engine_scan.py`` (default on TPU hosts; runs in interpret
-    mode elsewhere).  Integer Stats are bit-identical across backends —
+  * ``"jnp"``    — the pure-jnp all-sets scan below (CPU default);
+  * ``"pallas"`` — the fused Pallas kernel in ``kernels/engine_scan.py``
+    (default on TPU hosts; interpret mode on the CPU).  Integer Stats
+    are bit-identical across backends —
     both apply the same ``controller`` transition kernels in the same
     in-set order (tests/test_engine.py).
 
@@ -76,12 +78,9 @@ class BackendError(RuntimeError):
 def backend_status(backend: str) -> Tuple[bool, str]:
     """(supported, human-readable detail) for an engine backend name."""
     if backend == "jnp":
-        return True, "pure-jnp vmap-over-sets scan"
+        return True, "pure-jnp all-sets scan"
     if backend == "pallas":
-        try:
-            from ..kernels import engine_scan
-        except ImportError as e:  # pragma: no cover - host-dependent
-            return False, f"kernels.engine_scan import failed: {e}"
+        from ..kernels import engine_scan
         return engine_scan.supported()
     return False, f"unknown backend {backend!r}; choose from {BACKENDS}"
 
@@ -329,91 +328,38 @@ def decode_state(cfg: MorpheusConfig, state: EngineState,
 
 # ------------------------------------------------------------------ engine
 
-def _conv_trace_state(cfg: MorpheusConfig, rows0: ctl.ConvRow, tags, writes,
-                      pos, active, warmup) -> Tuple[ctl.ConvRow, Stats]:
-    """All conventional sets of one trace: initial rows -> (final rows,
-    summed Stats).  ``rows0`` leaves are (Sc, ways)."""
+def _scan_trace(cfg: MorpheusConfig, slot, rows0, cols):
+    """All sets of one tier of ONE trace: (S, ...) state rows and (S, L)
+    request columns -> (final rows, Stats summed over sets).  Sets travel
+    as columns (``controller`` layout); the scan runs over the L slots."""
+    rows = jax.tree.map(lambda x: x.T if x.ndim == 2 else x[None, :], rows0)
+    acc = jax.tree.map(lambda z: jnp.zeros((1, cols[0].shape[0]), z.dtype),
+                       ctl._zero_stats())
 
-    def one_set(r0, tag_l, w_l, p_l, a_l):
-        def body(carry, x):
-            row, acc = carry
-            t, w, p, a = x
-            new_row, out = ctl.conv_set_kernel(cfg, row, t, w)
-            row = jax.tree.map(lambda nn, oo: jnp.where(a, nn, oo),
-                               new_row, row)
-            m = a & (p >= warmup)
-            delta = ctl.request_stats(cfg, m, out, jnp.bool_(False),
-                                      ctl._NO_EXT)
-            return (row, jax.tree.map(jnp.add, acc, delta)), None
+    def body(carry, req):
+        row, acc = carry
+        row, delta = slot(cfg, row, *req)
+        return (row, jax.tree.map(jnp.add, acc, delta)), None
 
-        init = (r0, ctl._zero_stats())
-        (row, acc), _ = jax.lax.scan(body, init, (tag_l, w_l, p_l, a_l))
-        return row, acc
-
-    rows, per_set = jax.vmap(one_set)(rows0, tags, writes, pos, active)
-    return rows, jax.tree.map(lambda x: jnp.sum(x, axis=0), per_set)
+    (rows, acc), _ = jax.lax.scan(body, (rows, acc),
+                                  tuple(c.T[:, None, :] for c in cols))
+    rows = jax.tree.map(lambda x, x0: x.T if x0.ndim == 2 else x[0],
+                        rows, rows0)
+    return rows, jax.tree.map(jnp.sum, acc)
 
 
-def _ext_trace_state(cfg: MorpheusConfig, rows0: ctl.ExtRow, tags, writes,
-                     levels, pos, active, warmup) -> Tuple[ctl.ExtRow, Stats]:
-    """All extended sets of one trace: initial rows -> (final rows, summed
-    Stats).  ``rows0`` leaves are (Se, ...)."""
-
-    def one_set(r0, tag_l, w_l, l_l, p_l, a_l):
-        def body(carry, x):
-            row, acc = carry
-            t, w, l, p, a = x
-            new_row, out = ctl.ext_set_kernel(cfg, row, t, w, l)
-            row = jax.tree.map(lambda nn, oo: jnp.where(a, nn, oo),
-                               new_row, row)
-            m = a & (p >= warmup)
-            delta = ctl.request_stats(cfg, jnp.bool_(False), ctl._NO_CONV,
-                                      m, out)
-            return (row, jax.tree.map(jnp.add, acc, delta)), None
-
-        init = (r0, ctl._zero_stats())
-        (row, acc), _ = jax.lax.scan(body, init, (tag_l, w_l, l_l, p_l, a_l))
-        return row, acc
-
-    rows, per_set = jax.vmap(one_set)(rows0, tags, writes, levels, pos,
-                                      active)
-    return rows, jax.tree.map(lambda x: jnp.sum(x, axis=0), per_set)
-
-
-def _rows_zero(cfg: MorpheusConfig, zero_fn, n_sets: int):
-    """Stack a per-set zero row into (n_sets, ...) leaves."""
-    row = zero_fn(cfg)
-    return jax.tree.map(
-        lambda x: jnp.zeros((n_sets,) + x.shape, x.dtype), row)
+def _scan_tier(cfg: MorpheusConfig, slot, rows, cols):
+    """jnp engine: ``_scan_trace`` vmapped over the B traces."""
+    return jax.vmap(partial(_scan_trace, cfg, slot))(rows, cols)
 
 
 @partial(jax.jit, static_argnums=(0, 2))
 def _run_packed(cfg: MorpheusConfig, pt: PackedTraces,
                 backend: str = "jnp") -> Stats:
-    """Batched engine: PackedTraces -> Stats with (B,) leaves."""
-    if backend == "pallas":
-        from ..kernels import engine_scan
-        return engine_scan.run_packed(cfg, pt)
-    b = pt.warmup.shape[0]
-    total = jax.tree.map(
-        lambda z: jnp.zeros((b,) + z.shape, z.dtype), ctl._zero_stats())
-    if pt.conv_tag.shape[1] and pt.conv_tag.shape[2]:
-        rows0 = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (b,) + x.shape),
-            _rows_zero(cfg, ctl.conv_row_zero, pt.conv_tag.shape[1]))
-        _, conv = jax.vmap(partial(_conv_trace_state, cfg))(
-            rows0, pt.conv_tag, pt.conv_write, pt.conv_pos, pt.conv_active,
-            pt.warmup)
-        total = jax.tree.map(jnp.add, total, conv)
-    if pt.ext_tag.shape[1] and pt.ext_tag.shape[2]:
-        rows0 = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (b,) + x.shape),
-            _rows_zero(cfg, ctl.ext_row_zero, pt.ext_tag.shape[1]))
-        _, ext = jax.vmap(partial(_ext_trace_state, cfg))(
-            rows0, pt.ext_tag, pt.ext_write, pt.ext_level, pt.ext_pos,
-            pt.ext_active, pt.warmup)
-        total = jax.tree.map(jnp.add, total, ext)
-    return total
+    """Batched engine from cold caches: PackedTraces -> Stats with (B,)
+    leaves."""
+    state = init_state(cfg, pt.warmup.shape[0])
+    return _run_packed_state(cfg, pt, state, backend)[1]
 
 
 @partial(jax.jit, static_argnums=(0, 3))
@@ -422,38 +368,37 @@ def _run_packed_state(cfg: MorpheusConfig, pt: PackedTraces,
                       ) -> Tuple[EngineState, Stats]:
     """Stateful batched engine: one epoch of packed requests applied to an
     explicit carry.  Returns (new state, this epoch's Stats delta)."""
-    b = pt.warmup.shape[0]
-    delta = jax.tree.map(
-        lambda z: jnp.zeros((b,) + z.shape, z.dtype), ctl._zero_stats())
     if backend == "pallas":
         from ..kernels import engine_scan
-        state, delta = engine_scan.run_packed_state(cfg, pt, state)
+        scan = engine_scan.scan_tier
     else:
-        if pt.conv_tag.shape[1] and pt.conv_tag.shape[2]:
-            rows0 = ctl.ConvRow(state.conv_tags, state.conv_valid,
-                                state.conv_dirty, state.conv_lru)
-            rows, conv = jax.vmap(partial(_conv_trace_state, cfg))(
-                rows0, pt.conv_tag, pt.conv_write, pt.conv_pos,
-                pt.conv_active, pt.warmup)
-            delta = jax.tree.map(jnp.add, delta, conv)
-            state = state._replace(conv_tags=rows.tags,
-                                   conv_valid=rows.valid,
-                                   conv_dirty=rows.dirty,
-                                   conv_lru=rows.lru)
-        if pt.ext_tag.shape[1] and pt.ext_tag.shape[2]:
-            rows0 = ctl.ExtRow(state.ext_tags, state.ext_valid,
-                               state.ext_dirty, state.ext_lru,
-                               state.ext_size, state.ext_used,
-                               state.bf1, state.bf2, state.n_mru)
-            rows, ext = jax.vmap(partial(_ext_trace_state, cfg))(
-                rows0, pt.ext_tag, pt.ext_write, pt.ext_level, pt.ext_pos,
-                pt.ext_active, pt.warmup)
-            delta = jax.tree.map(jnp.add, delta, ext)
-            state = state._replace(ext_tags=rows.tags, ext_valid=rows.valid,
-                                   ext_dirty=rows.dirty, ext_lru=rows.lru,
-                                   ext_size=rows.size, ext_used=rows.used,
-                                   bf1=rows.bf1, bf2=rows.bf2,
-                                   n_mru=rows.n_mru)
+        scan = _scan_tier
+    b = pt.warmup.shape[0]
+    warm = pt.warmup[:, None, None]
+    delta = jax.tree.map(
+        lambda z: jnp.zeros((b,) + z.shape, z.dtype), ctl._zero_stats())
+    if pt.conv_tag.shape[1] and pt.conv_tag.shape[2]:
+        rows, d = scan(cfg, ctl.conv_slot,
+                       ctl.ConvRow(state.conv_tags, state.conv_valid,
+                                   state.conv_dirty, state.conv_lru),
+                       (pt.conv_tag, pt.conv_write, pt.conv_active,
+                        pt.conv_active & (pt.conv_pos >= warm)))
+        delta = jax.tree.map(jnp.add, delta, d)
+        state = state._replace(conv_tags=rows.tags, conv_valid=rows.valid,
+                               conv_dirty=rows.dirty, conv_lru=rows.lru)
+    if pt.ext_tag.shape[1] and pt.ext_tag.shape[2]:
+        rows, d = scan(cfg, ctl.ext_slot,
+                       ctl.ExtRow(state.ext_tags, state.ext_valid,
+                                  state.ext_dirty, state.ext_lru,
+                                  state.ext_size, state.ext_used,
+                                  state.bf1, state.bf2, state.n_mru),
+                       (pt.ext_tag, pt.ext_write, pt.ext_level,
+                        pt.ext_active, pt.ext_active & (pt.ext_pos >= warm)))
+        delta = jax.tree.map(jnp.add, delta, d)
+        state = state._replace(ext_tags=rows.tags, ext_valid=rows.valid,
+                               ext_dirty=rows.dirty, ext_lru=rows.lru,
+                               ext_size=rows.size, ext_used=rows.used,
+                               bf1=rows.bf1, bf2=rows.bf2, n_mru=rows.n_mru)
     n_req = jnp.zeros((b,), jnp.int32)
     if pt.conv_active.shape[1] and pt.conv_active.shape[2]:
         n_req = n_req + pt.conv_active.sum(axis=(1, 2)).astype(jnp.int32)

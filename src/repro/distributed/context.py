@@ -11,26 +11,9 @@ from __future__ import annotations
 from contextlib import contextmanager
 from typing import Optional
 
-import jax
 from jax.sharding import Mesh
 
 _ACTIVE_MESH: Optional[Mesh] = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-tolerant ``shard_map``.
-
-    Newer JAX exposes ``jax.shard_map`` (with ``check_vma``); older
-    releases only have ``jax.experimental.shard_map.shard_map`` (where the
-    same knob is called ``check_rep``).  Layers import it from here so the
-    perf-rewrite paths work on both APIs.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:

@@ -6,7 +6,8 @@ hash bits per request against the per-set 32-byte filters in one VMEM
 pass, and (for inserts) produces the OR-masks to apply.
 
 Inputs arrive pre-gathered (filters row per query) — the set-index gather
-is a cheap XLA op; the kernel does the bit math where the parallelism is.
+is a cheap XLA op; the kernel does the bit math where the parallelism is,
+with queries on lanes (QUERY_BLOCK per grid step).
 """
 from __future__ import annotations
 
@@ -16,64 +17,39 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..core.bloom import _HASH_MULTIPLIERS, NUM_HASHES
+from ..core.bloom import _bit_mask, _hash_bits, _test
 
 QUERY_BLOCK = 512
 
 
-def _hash_bits(tag, num_bits):
-    out = []
-    for i in range(NUM_HASHES):
-        mul = jnp.uint32(_HASH_MULTIPLIERS[i])
-        h = (tag * mul) ^ ((tag * mul) >> jnp.uint32(15))
-        out.append((h % jnp.uint32(num_bits)).astype(jnp.int32))
-    return out  # list of (Q,) int32
-
-
 def _query_kernel(filters_ref, tags_ref, pred_ref, masks_ref):
-    filters = filters_ref[...]                  # (Q, words) uint32
-    tags = tags_ref[...].astype(jnp.uint32)     # (Q,)
-    q, words = filters.shape
-    bits_list = _hash_bits(tags, words * 32)
-
-    present = jnp.ones((q,), jnp.bool_)
-    masks = jnp.zeros_like(filters)
-    w_iota = jax.lax.broadcasted_iota(jnp.int32, (q, words), 1)
-    for bits in bits_list:
-        word_idx = bits // 32                   # (Q,)
-        bit = (bits % 32).astype(jnp.uint32)
-        onehot = w_iota == word_idx[:, None]    # (Q, words)
-        # test: pick the word via one-hot OR-select
-        sel = jnp.where(onehot, filters, jnp.uint32(0))
-        word = sel[:, 0]
-        for i in range(1, words):
-            word = word | sel[:, i]
-        present = present & (((word >> bit) & jnp.uint32(1)) == 1)
-        # insert mask
-        masks = masks | jnp.where(onehot, (jnp.uint32(1) << bit)[:, None],
-                                  jnp.uint32(0))
-
-    pred_ref[...] = present.astype(jnp.int32)
-    masks_ref[...] = masks
+    filters = filters_ref[...]                  # (words, QB) uint32
+    bits = _hash_bits(tags_ref[...], filters.shape[0] * 32)   # (1, QB)s
+    pred_ref[...] = _test(filters, bits).astype(jnp.int32)
+    masks_ref[...] = _bit_mask(bits, filters.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bloom_query(filters: jnp.ndarray, tags: jnp.ndarray, *,
-                interpret: bool = True):
+                interpret: bool):
     """filters (Q, words) u32 pre-gathered; tags (Q,) u32.
 
-    Returns (predicted (Q,) i32, insert_masks (Q, words) u32)."""
+    Returns (predicted (Q,) i32, insert_masks (Q, words) u32).  Inside the
+    kernel each query is one column — the engine's Bloom layout, so the
+    bit math is ``core.bloom``'s own."""
     q, words = filters.shape
     qb = min(QUERY_BLOCK, q)
     assert q % qb == 0, (q, qb)
-    return pl.pallas_call(
+    col = lambda i: (0, i)
+    pred, masks = pl.pallas_call(
         _query_kernel,
         grid=(q // qb,),
-        in_specs=[pl.BlockSpec((qb, words), lambda i: (i, 0)),
-                  pl.BlockSpec((qb,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((qb,), lambda i: (i,)),
-                   pl.BlockSpec((qb, words), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((q,), jnp.int32),
-                   jax.ShapeDtypeStruct((q, words), jnp.uint32)],
+        in_specs=[pl.BlockSpec((words, qb), col),
+                  pl.BlockSpec((1, qb), col)],
+        out_specs=[pl.BlockSpec((1, qb), col),
+                   pl.BlockSpec((words, qb), col)],
+        out_shape=[jax.ShapeDtypeStruct((1, q), jnp.int32),
+                   jax.ShapeDtypeStruct((words, q), jnp.uint32)],
         interpret=interpret,
-    )(filters, tags)
+    )(filters.T, tags[None, :].astype(jnp.uint32))
+    return pred[0], masks.T

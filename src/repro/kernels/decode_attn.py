@@ -75,7 +75,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "t_block"))
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     valid: jnp.ndarray, *, interpret: bool = True,
+                     valid: jnp.ndarray, *, interpret: bool,
                      t_block: int = T_BLOCK):
     """q (B,H,hd); k/v (B,T,KV,hd); valid (B,T) -> (B,H,hd) f32."""
     b, h, hd = q.shape

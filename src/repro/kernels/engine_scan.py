@@ -1,41 +1,39 @@
-"""Pallas kernel: fused per-set cache-engine transition scan.
+"""Pallas kernel: fused cache-engine transition scan over all sets.
 
 This is the engine's hot path — the per-set state machine of
-``core/engine._run_packed`` — as a purpose-built kernel, in the spirit of
-the Morpheus helper kernel itself (and of assist-warp designs like
+``core/engine._run_packed_state`` — as a purpose-built kernel, in the spirit
+of the Morpheus helper kernel itself (and of assist-warp designs like
 CALDERA, arXiv:1602.01348): move the bottleneck state machine into a
 kernel that lives next to the memory it manages.
 
-Layout (mirrors ``core/engine.pack``):
+Layout (sets on lanes, the form Mosaic lowers):
 
-  * grid = (B, S): one program instance owns ONE set's padded request
-    subsequence of one trace — the Pallas analogue of the jnp engine's
-    ``vmap`` over sets, and of "one warp owns one cache set" in the paper.
-  * in_specs: the packed (B, S, L) trace columns, block (1, 1, L) — each
-    instance sees only its own subsequence (tag / write / level plus the
-    ``active`` padding mask and the warmup ``stats mask``).
-  * scratch (VMEM): the set's mutable state rows — tags / valid / dirty /
-    LRU (+ size, byte budget ``used``, and the two Bloom filters on the
-    extended tier).  Scratch persists across sequential grid steps on TPU,
-    so every instance re-zeroes it first (a fresh cache set).
-  * body: ``lax.fori_loop`` over the L slots, applying the SAME pure
-    per-set transition kernels the serial oracle runs
-    (``controller.conv_set_kernel`` / ``ext_set_kernel``) and accumulating
-    the per-request ``controller.request_stats`` deltas in the loop carry
-    (int32 counters exact, float32 sums in in-set order).
-  * out_specs: per-set Stats vectors (B, S, n_int) int32 and (B, S,
-    n_float) float32, reduced over sets by the caller.
+  * grid = (B, L / Lc): one program instance per (trace, chunk of Lc
+    request slots).  The slot axis is sequential ("arbitrary"): chunks of
+    one trace run in order and the state stays resident in VMEM across
+    them.
+  * in_specs: the packed request columns, transposed to (B, L, S) and
+    tiled (1, Lc, S) — one sublane row per slot, one lane per set.  Slot
+    ``t`` of every set is one dynamic-row load ``col[0, t]``.
+  * state: every row leaf as a (1, ways, S) / (1, words, S) / (1, 1, S)
+    block (one column per set; bools as int32).  Instance 0 of a trace
+    copies the input state into the output block, which then carries the
+    state across the slot chunks.
+  * body: ``lax.fori_loop`` over the chunk's slots, applying the SAME
+    column transition kernels the serial oracle and the jnp engine run
+    (``controller.conv_slot`` / ``ext_slot``) to all S sets at once, and
+    accumulating the per-set Stats deltas in the loop carry (int32
+    counters exact, float32 sums in in-set order).
+  * out_specs: per-set Stats rows (B, n_int, S) int32 and (B, n_float, S)
+    float32, reduced over sets by the caller, plus the final state.
 
 Because the transition functions are literally shared with the serial
 ``lax.scan`` oracle and the jnp engine, the integer Stats are bit-identical
-across all three paths (property-tested in tests/test_engine.py).
-
-Interpret-mode caveats: on CPU (this container) the kernel runs with
-``interpret=True`` — functionally identical, but the grid is emulated
-sequentially, so it is a correctness/portability path, not a fast path
-(``backend="jnp"`` stays the CPU default).  The controller kernels use 1-D
-``jnp.arange``/``argmax`` idioms that Mosaic only accepts in 2-D form, so
-compiled-TPU lowering may need the iota reshapes noted in docs/kernels.md.
+across all three paths (property-tested in tests/test_engine.py).  The
+kernels only use 2-D values, int32/bool reductions over the ways axis and
+dynamic sublane-row loads, so they compile for TPU v5e
+(tests/test_tpu_compile.py).  On the CPU backend they run in interpret mode
+(``kernels.ops.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -44,18 +42,14 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces; absent on some non-TPU jax builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - exercised via backend_status
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import controller as ctl
-from ..core.controller import MorpheusConfig, Stats
+from ..core.controller import Stats
+from . import ops
 
-# Stats layout inside the kernel: one int32 vector + one float32 vector,
+# Stats layout inside the kernel: one int32 block + one float32 block,
 # field order inherited from the Stats NamedTuple.
 INT_FIELDS: Tuple[str, ...] = tuple(
     f for f in Stats._fields if f in ctl._INT_FIELDS)
@@ -63,379 +57,106 @@ FLOAT_FIELDS: Tuple[str, ...] = tuple(
     f for f in Stats._fields if f not in ctl._INT_FIELDS)
 _NI, _NF = len(INT_FIELDS), len(FLOAT_FIELDS)
 
-
-def _delta_vecs(delta: Stats) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Stats delta (scalar leaves) -> (int32 (NI,), float32 (NF,))."""
-    ints = jnp.stack([jnp.asarray(getattr(delta, f), jnp.int32)
-                      for f in INT_FIELDS])
-    flts = jnp.stack([jnp.asarray(getattr(delta, f), jnp.float32)
-                      for f in FLOAT_FIELDS])
-    return ints, flts
-
-
-def _vecs_to_stats(ints: jnp.ndarray, flts: jnp.ndarray) -> Stats:
-    """(..., NI) int32 + (..., NF) float32 -> Stats with (...,) leaves."""
-    vals = {f: ints[..., i] for i, f in enumerate(INT_FIELDS)}
-    vals.update({f: flts[..., i] for i, f in enumerate(FLOAT_FIELDS)})
-    return Stats(**vals)
+# request slots per grid step (the packed L is a power of two)
+SLOT_CHUNK = 128
 
 
 def supported() -> Tuple[bool, str]:
     """Whether this kernel can run on the current host, and how."""
-    if pltpu is None:
-        return False, "jax.experimental.pallas.tpu is not importable"
     plat = jax.default_backend()
     if plat == "tpu":
         return True, "compiled Mosaic kernel"
     if plat == "cpu":
         return True, "interpret mode (CPU host)"
-    return False, f"no Pallas lowering for '{plat}' hosts"
+    return False, f"no Pallas TPU lowering for '{plat}' hosts"
 
 
-# ------------------------------------------------------------------ kernels
+def _scan_kernel(cfg, slot, row_type, col_bool, row_bool, *refs):
+    """One (trace, slot chunk): replay the chunk's slots on every set."""
+    n_col, n_row = len(col_bool), len(row_bool)
+    cols = refs[:n_col]
+    rows_in = refs[n_col:n_col + n_row]
+    ints_ref, flts_ref = refs[n_col + n_row:n_col + n_row + 2]
+    rows = refs[n_col + n_row + 2:]
 
-def _conv_scan_kernel(cfg: MorpheusConfig, tag_ref, write_ref, active_ref,
-                      mask_ref, ints_ref, flts_ref,
-                      tags_s, valid_s, dirty_s, lru_s):
-    """One conventional set's full subsequence: scan slots, carry state in
-    scratch, accumulate the Stats delta vectors in the loop carry."""
-    tags_s[...] = jnp.zeros_like(tags_s)
-    valid_s[...] = jnp.zeros_like(valid_s)
-    dirty_s[...] = jnp.zeros_like(dirty_s)
-    lru_s[...] = jnp.zeros_like(lru_s)
-    tag = tag_ref[0, 0, :]
-    write = write_ref[0, 0, :]
-    active = active_ref[0, 0, :]
-    mask = mask_ref[0, 0, :]
-
-    def body(t, acc):
-        ints, flts = acc
-        row = ctl.ConvRow(tags_s[0], valid_s[0] != 0, dirty_s[0] != 0,
-                          lru_s[0])
-        tg = jax.lax.dynamic_index_in_dim(tag, t, keepdims=False)
-        wr = jax.lax.dynamic_index_in_dim(write, t, keepdims=False) != 0
-        a = jax.lax.dynamic_index_in_dim(active, t, keepdims=False) != 0
-        m = jax.lax.dynamic_index_in_dim(mask, t, keepdims=False) != 0
-        new_row, out = ctl.conv_set_kernel(cfg, row, tg, wr)
-        tags_s[0] = jnp.where(a, new_row.tags, row.tags)
-        valid_s[0] = jnp.where(a, new_row.valid, row.valid).astype(jnp.int32)
-        dirty_s[0] = jnp.where(a, new_row.dirty, row.dirty).astype(jnp.int32)
-        lru_s[0] = jnp.where(a, new_row.lru, row.lru)
-        delta = ctl.request_stats(cfg, m, out, np.bool_(False), ctl._NO_EXT)
-        iv, fv = _delta_vecs(delta)
-        return ints + iv, flts + fv
-
-    ints, flts = jax.lax.fori_loop(
-        0, tag.shape[0], body,
-        (jnp.zeros((_NI,), jnp.int32), jnp.zeros((_NF,), jnp.float32)))
-    ints_ref[0, 0, :] = ints
-    flts_ref[0, 0, :] = flts
-
-
-def _ext_scan_kernel(cfg: MorpheusConfig, tag_ref, write_ref, level_ref,
-                     active_ref, mask_ref, ints_ref, flts_ref,
-                     tags_s, valid_s, dirty_s, lru_s, size_s, bf1_s, bf2_s):
-    """One extended set's subsequence: predict -> lookup -> touch/insert per
-    slot.  Vector state (ways / Bloom words) lives in scratch; the scalar
-    byte budget and MRU count ride in the loop carry."""
-    tags_s[...] = jnp.zeros_like(tags_s)
-    valid_s[...] = jnp.zeros_like(valid_s)
-    dirty_s[...] = jnp.zeros_like(dirty_s)
-    lru_s[...] = jnp.zeros_like(lru_s)
-    size_s[...] = jnp.zeros_like(size_s)
-    bf1_s[...] = jnp.zeros_like(bf1_s)
-    bf2_s[...] = jnp.zeros_like(bf2_s)
-    tag = tag_ref[0, 0, :]
-    write = write_ref[0, 0, :]
-    level = level_ref[0, 0, :]
-    active = active_ref[0, 0, :]
-    mask = mask_ref[0, 0, :]
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        for src, dst in zip(rows_in, rows):
+            dst[...] = src[...]
+        ints_ref[...] = jnp.zeros_like(ints_ref)
+        flts_ref[...] = jnp.zeros_like(flts_ref)
 
     def body(t, acc):
-        used, n_mru, ints, flts = acc
-        row = ctl.ExtRow(tags_s[0], valid_s[0] != 0, dirty_s[0] != 0,
-                         lru_s[0], size_s[0], used, bf1_s[0], bf2_s[0],
-                         n_mru)
-        tg = jax.lax.dynamic_index_in_dim(tag, t, keepdims=False)
-        wr = jax.lax.dynamic_index_in_dim(write, t, keepdims=False) != 0
-        lv = jax.lax.dynamic_index_in_dim(level, t, keepdims=False)
-        a = jax.lax.dynamic_index_in_dim(active, t, keepdims=False) != 0
-        m = jax.lax.dynamic_index_in_dim(mask, t, keepdims=False) != 0
-        new_row, out = ctl.ext_set_kernel(cfg, row, tg, wr, lv)
-        tags_s[0] = jnp.where(a, new_row.tags, row.tags)
-        valid_s[0] = jnp.where(a, new_row.valid, row.valid).astype(jnp.int32)
-        dirty_s[0] = jnp.where(a, new_row.dirty, row.dirty).astype(jnp.int32)
-        lru_s[0] = jnp.where(a, new_row.lru, row.lru)
-        size_s[0] = jnp.where(a, new_row.size, row.size)
-        bf1_s[0] = jnp.where(a, new_row.bf1, row.bf1)
-        bf2_s[0] = jnp.where(a, new_row.bf2, row.bf2)
-        used = jnp.where(a, new_row.used, used)
-        n_mru = jnp.where(a, new_row.n_mru, n_mru)
-        delta = ctl.request_stats(cfg, np.bool_(False), ctl._NO_CONV, m, out)
-        iv, fv = _delta_vecs(delta)
-        return used, n_mru, ints + iv, flts + fv
+        req = [c[0, pl.ds(t, 1), :] for c in cols]
+        req = [r != 0 if b else r for r, b in zip(req, col_bool)]
+        row = row_type(*[r[0] != 0 if b else r[0]
+                         for r, b in zip(rows, row_bool)])
+        row, delta = slot(cfg, row, *req)
+        for r, v, b in zip(rows, row, row_bool):
+            r[0] = v.astype(jnp.int32) if b else v
+        return jax.tree.map(jnp.add, acc, delta)
 
-    _, _, ints, flts = jax.lax.fori_loop(
-        0, tag.shape[0], body,
-        (jnp.int32(0), jnp.int32(0),
-         jnp.zeros((_NI,), jnp.int32), jnp.zeros((_NF,), jnp.float32)))
-    ints_ref[0, 0, :] = ints
-    flts_ref[0, 0, :] = flts
+    acc = Stats(**{f: ints_ref[0, i:i + 1, :]
+                   for i, f in enumerate(INT_FIELDS)},
+                **{f: flts_ref[0, i:i + 1, :]
+                   for i, f in enumerate(FLOAT_FIELDS)})
+    acc = jax.lax.fori_loop(0, cols[0].shape[1], body, acc)
+    for i, f in enumerate(INT_FIELDS):
+        ints_ref[0, i:i + 1, :] = getattr(acc, f)
+    for i, f in enumerate(FLOAT_FIELDS):
+        flts_ref[0, i:i + 1, :] = getattr(acc, f)
 
 
-# ------------------------------------------------------------------ drivers
+def _columns(x: jnp.ndarray) -> jnp.ndarray:
+    """(B, S, w) state leaf -> (B, w, S); (B, S) per-set scalar -> (B, 1, S);
+    bools as int32 (Mosaic keeps no bool memory)."""
+    x = jnp.swapaxes(x, 1, 2) if x.ndim == 3 else x[:, None, :]
+    return x.astype(jnp.int32) if x.dtype == jnp.bool_ else x
 
-def _per_set_call(kernel, n_inputs: int, b: int, s: int, length: int,
-                  scratch, interpret: bool):
-    """pallas_call plumbing shared by the two tiers: grid (B, S), one
-    (1, 1, L) block per input column, per-set Stats vector outputs."""
-    col = pl.BlockSpec((1, 1, length), lambda i, j: (i, j, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(b, s),
-        in_specs=[col] * n_inputs,
-        out_specs=[pl.BlockSpec((1, 1, _NI), lambda i, j: (i, j, 0)),
-                   pl.BlockSpec((1, 1, _NF), lambda i, j: (i, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, s, _NI), jnp.int32),
-                   jax.ShapeDtypeStruct((b, s, _NF), jnp.float32)],
-        scratch_shapes=scratch,
+
+def scan_tier(cfg: ctl.MorpheusConfig, slot, rows, cols, *,
+              interpret: bool | None = None):
+    """Pallas twin of ``core.engine._scan_tier``: replay B traces' packed
+    request columns on one tier.
+
+    ``slot`` is ``controller.conv_slot`` or ``ext_slot``; ``rows`` its row
+    NamedTuple with (B, S, ...) state leaves; ``cols`` the slot's request
+    columns, each (B, S, L), counted mask last.  Returns (final rows,
+    Stats with (B,) leaves)."""
+    interpret = ops.interpret_mode() if interpret is None else interpret
+    b, s, length = cols[0].shape
+    lc = SLOT_CHUNK if length % SLOT_CHUNK == 0 else length
+    col_bool = tuple(c.dtype == jnp.bool_ for c in cols)
+    row_bool = tuple(x.dtype == jnp.bool_ for x in rows)
+    cols_k = [jnp.swapaxes(c, 1, 2) for c in cols]
+    cols_k = [c.astype(jnp.int32) if c.dtype == jnp.bool_ else c
+              for c in cols_k]
+    rows_k = [_columns(x) for x in rows]
+    out_shape = ([jax.ShapeDtypeStruct((b, _NI, s), jnp.int32),
+                  jax.ShapeDtypeStruct((b, _NF, s), jnp.float32)]
+                 + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in rows_k])
+
+    def whole(x):
+        return pl.BlockSpec((1,) + tuple(x.shape[1:]), lambda i, j: (i, 0, 0))
+
+    ints, flts, *new = pl.pallas_call(
+        functools.partial(_scan_kernel, cfg, slot, type(rows), col_bool,
+                          row_bool),
+        grid=(b, length // lc),
+        in_specs=([pl.BlockSpec((1, lc, s), lambda i, j: (i, j, 0))]
+                  * len(cols_k) + [whole(x) for x in rows_k]),
+        out_specs=[whole(o) for o in out_shape],
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )
-
-
-def conv_scan(cfg: MorpheusConfig, tag, write, active, mask,
-              *, interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """All conventional sets of a packed batch -> per-set Stats vectors.
-
-    tag (B, S, L) uint32; write/active/mask (B, S, L) int32 masks.
-    Returns ((B, S, NI) int32, (B, S, NF) float32).
-    """
-    b, s, length = tag.shape
-    w = cfg.conv_ways
-    scratch = [pltpu.VMEM((1, w), jnp.uint32), pltpu.VMEM((1, w), jnp.int32),
-               pltpu.VMEM((1, w), jnp.int32), pltpu.VMEM((1, w), jnp.uint32)]
-    call = _per_set_call(functools.partial(_conv_scan_kernel, cfg), 4,
-                         b, s, length, scratch, interpret)
-    return call(tag, write, active, mask)
-
-
-def ext_scan(cfg: MorpheusConfig, tag, write, level, active, mask,
-             *, interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """All extended sets of a packed batch -> per-set Stats vectors."""
-    b, s, length = tag.shape
-    w = cfg.ext_max_ways
-    words = ctl.BLOOM_WORDS
-    scratch = [pltpu.VMEM((1, w), jnp.uint32), pltpu.VMEM((1, w), jnp.int32),
-               pltpu.VMEM((1, w), jnp.int32), pltpu.VMEM((1, w), jnp.uint32),
-               pltpu.VMEM((1, w), jnp.int32),
-               pltpu.VMEM((1, words), jnp.uint32),
-               pltpu.VMEM((1, words), jnp.uint32)]
-    call = _per_set_call(functools.partial(_ext_scan_kernel, cfg), 5,
-                         b, s, length, scratch, interpret)
-    return call(tag, write, level, active, mask)
-
-
-# ------------------------------------------------------- stateful kernels
-#
-# The epoch-streaming runtime (core/engine.advance_packed, runtime/stream)
-# needs the same scan with an explicit carry: initial state rows arrive as
-# kernel inputs, final rows leave as outputs.  The rows are small (ways /
-# Bloom words), so they ride in the fori_loop carry directly — no scratch.
-# The transition kernels are still controller.conv_set_kernel /
-# ext_set_kernel, so integer Stats remain bit-identical to the monolithic
-# kernels above and to the serial oracle.
-
-def _conv_state_kernel(cfg: MorpheusConfig, tag_ref, write_ref, active_ref,
-                       mask_ref, tags0_ref, valid0_ref, dirty0_ref, lru0_ref,
-                       ints_ref, flts_ref, tags1_ref, valid1_ref, dirty1_ref,
-                       lru1_ref):
-    """One conventional set's epoch slice: carry state in -> state out."""
-    tag = tag_ref[0, 0, :]
-    write = write_ref[0, 0, :]
-    active = active_ref[0, 0, :]
-    mask = mask_ref[0, 0, :]
-    row0 = ctl.ConvRow(tags0_ref[0, 0, :], valid0_ref[0, 0, :] != 0,
-                       dirty0_ref[0, 0, :] != 0, lru0_ref[0, 0, :])
-
-    def body(t, carry):
-        row, ints, flts = carry
-        tg = jax.lax.dynamic_index_in_dim(tag, t, keepdims=False)
-        wr = jax.lax.dynamic_index_in_dim(write, t, keepdims=False) != 0
-        a = jax.lax.dynamic_index_in_dim(active, t, keepdims=False) != 0
-        m = jax.lax.dynamic_index_in_dim(mask, t, keepdims=False) != 0
-        new_row, out = ctl.conv_set_kernel(cfg, row, tg, wr)
-        row = jax.tree.map(lambda nn, oo: jnp.where(a, nn, oo), new_row, row)
-        delta = ctl.request_stats(cfg, m, out, np.bool_(False), ctl._NO_EXT)
-        iv, fv = _delta_vecs(delta)
-        return row, ints + iv, flts + fv
-
-    row, ints, flts = jax.lax.fori_loop(
-        0, tag.shape[0], body,
-        (row0, jnp.zeros((_NI,), jnp.int32), jnp.zeros((_NF,), jnp.float32)))
-    ints_ref[0, 0, :] = ints
-    flts_ref[0, 0, :] = flts
-    tags1_ref[0, 0, :] = row.tags
-    valid1_ref[0, 0, :] = row.valid.astype(jnp.int32)
-    dirty1_ref[0, 0, :] = row.dirty.astype(jnp.int32)
-    lru1_ref[0, 0, :] = row.lru
-
-
-def _ext_state_kernel(cfg: MorpheusConfig, tag_ref, write_ref, level_ref,
-                      active_ref, mask_ref, tags0_ref, valid0_ref, dirty0_ref,
-                      lru0_ref, size0_ref, bf1_0_ref, bf2_0_ref, sca0_ref,
-                      ints_ref, flts_ref, tags1_ref, valid1_ref, dirty1_ref,
-                      lru1_ref, size1_ref, bf1_1_ref, bf2_1_ref, sca1_ref):
-    """One extended set's epoch slice with explicit carry.  The two scalar
-    state words (byte budget ``used``, Bloom MRU count ``n_mru``) travel as
-    a (1, 1, 2) int32 vector."""
-    tag = tag_ref[0, 0, :]
-    write = write_ref[0, 0, :]
-    level = level_ref[0, 0, :]
-    active = active_ref[0, 0, :]
-    mask = mask_ref[0, 0, :]
-    row0 = ctl.ExtRow(tags0_ref[0, 0, :], valid0_ref[0, 0, :] != 0,
-                      dirty0_ref[0, 0, :] != 0, lru0_ref[0, 0, :],
-                      size0_ref[0, 0, :], sca0_ref[0, 0, 0],
-                      bf1_0_ref[0, 0, :], bf2_0_ref[0, 0, :],
-                      sca0_ref[0, 0, 1])
-
-    def body(t, carry):
-        row, ints, flts = carry
-        tg = jax.lax.dynamic_index_in_dim(tag, t, keepdims=False)
-        wr = jax.lax.dynamic_index_in_dim(write, t, keepdims=False) != 0
-        lv = jax.lax.dynamic_index_in_dim(level, t, keepdims=False)
-        a = jax.lax.dynamic_index_in_dim(active, t, keepdims=False) != 0
-        m = jax.lax.dynamic_index_in_dim(mask, t, keepdims=False) != 0
-        new_row, out = ctl.ext_set_kernel(cfg, row, tg, wr, lv)
-        row = jax.tree.map(lambda nn, oo: jnp.where(a, nn, oo), new_row, row)
-        delta = ctl.request_stats(cfg, np.bool_(False), ctl._NO_CONV, m, out)
-        iv, fv = _delta_vecs(delta)
-        return row, ints + iv, flts + fv
-
-    row, ints, flts = jax.lax.fori_loop(
-        0, tag.shape[0], body,
-        (row0, jnp.zeros((_NI,), jnp.int32), jnp.zeros((_NF,), jnp.float32)))
-    ints_ref[0, 0, :] = ints
-    flts_ref[0, 0, :] = flts
-    tags1_ref[0, 0, :] = row.tags
-    valid1_ref[0, 0, :] = row.valid.astype(jnp.int32)
-    dirty1_ref[0, 0, :] = row.dirty.astype(jnp.int32)
-    lru1_ref[0, 0, :] = row.lru
-    size1_ref[0, 0, :] = row.size
-    bf1_1_ref[0, 0, :] = row.bf1
-    bf2_1_ref[0, 0, :] = row.bf2
-    sca1_ref[0, 0, :] = jnp.stack([row.used, row.n_mru])
-
-
-def _state_call(kernel, b: int, s: int, length: int,
-                in_widths, out_widths, interpret: bool):
-    """pallas_call plumbing for the stateful kernels: grid (B, S); every
-    input/output is one (1, 1, w) block per instance."""
-    def spec(w):
-        return pl.BlockSpec((1, 1, w), lambda i, j: (i, j, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(b, s),
-        in_specs=[spec(w) for w in in_widths],
-        out_specs=[spec(w) for w, _ in out_widths],
-        out_shape=[jax.ShapeDtypeStruct((b, s, w), dt)
-                   for w, dt in out_widths],
-        interpret=interpret,
-    )
-
-
-def run_packed_state(cfg: MorpheusConfig, pt, state, *,
-                     interpret: bool | None = None):
-    """Stateful Pallas twin of ``core.engine._run_packed_state``'s jnp
-    path: (PackedTraces, EngineState) -> (EngineState', Stats delta).
-
-    Stats accumulation into ``state.stats`` and the ``pos`` advance are
-    left to the caller (``core.engine._run_packed_state``), which shares
-    that logic across backends."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b = pt.warmup.shape[0]
-    ints = jnp.zeros((b, _NI), jnp.int32)
-    flts = jnp.zeros((b, _NF), jnp.float32)
-    warm = pt.warmup[:, None, None]
-    if pt.conv_tag.shape[1] and pt.conv_tag.shape[2]:
-        _, s, length = pt.conv_tag.shape
-        w = cfg.conv_ways
-        mask = (pt.conv_active & (pt.conv_pos >= warm)).astype(jnp.int32)
-        call = _state_call(
-            functools.partial(_conv_state_kernel, cfg), b, s, length,
-            in_widths=[length] * 4 + [w] * 4,
-            out_widths=[(_NI, jnp.int32), (_NF, jnp.float32),
-                        (w, jnp.uint32), (w, jnp.int32), (w, jnp.int32),
-                        (w, jnp.uint32)],
-            interpret=interpret)
-        iv, fv, t1, v1, d1, l1 = call(
-            jnp.asarray(pt.conv_tag, jnp.uint32),
-            jnp.asarray(pt.conv_write, jnp.int32),
-            jnp.asarray(pt.conv_active, jnp.int32), mask,
-            state.conv_tags, state.conv_valid.astype(jnp.int32),
-            state.conv_dirty.astype(jnp.int32), state.conv_lru)
-        ints = ints + iv.sum(axis=1)
-        flts = flts + fv.sum(axis=1)
-        state = state._replace(conv_tags=t1, conv_valid=v1 != 0,
-                               conv_dirty=d1 != 0, conv_lru=l1)
-    if pt.ext_tag.shape[1] and pt.ext_tag.shape[2]:
-        _, s, length = pt.ext_tag.shape
-        w = cfg.ext_max_ways
-        words = ctl.BLOOM_WORDS
-        mask = (pt.ext_active & (pt.ext_pos >= warm)).astype(jnp.int32)
-        sca0 = jnp.stack([state.ext_used, state.n_mru], axis=-1)
-        call = _state_call(
-            functools.partial(_ext_state_kernel, cfg), b, s, length,
-            in_widths=[length] * 5 + [w] * 5 + [words] * 2 + [2],
-            out_widths=[(_NI, jnp.int32), (_NF, jnp.float32),
-                        (w, jnp.uint32), (w, jnp.int32), (w, jnp.int32),
-                        (w, jnp.uint32), (w, jnp.int32),
-                        (words, jnp.uint32), (words, jnp.uint32),
-                        (2, jnp.int32)],
-            interpret=interpret)
-        (iv, fv, t1, v1, d1, l1, s1, b1, b2, sca1) = call(
-            jnp.asarray(pt.ext_tag, jnp.uint32),
-            jnp.asarray(pt.ext_write, jnp.int32),
-            jnp.asarray(pt.ext_level, jnp.int32),
-            jnp.asarray(pt.ext_active, jnp.int32), mask,
-            state.ext_tags, state.ext_valid.astype(jnp.int32),
-            state.ext_dirty.astype(jnp.int32), state.ext_lru,
-            state.ext_size, state.bf1, state.bf2, sca0)
-        ints = ints + iv.sum(axis=1)
-        flts = flts + fv.sum(axis=1)
-        state = state._replace(ext_tags=t1, ext_valid=v1 != 0,
-                               ext_dirty=d1 != 0, ext_lru=l1, ext_size=s1,
-                               bf1=b1, bf2=b2, ext_used=sca1[..., 0],
-                               n_mru=sca1[..., 1])
-    return state, _vecs_to_stats(ints, flts)
-
-
-def run_packed(cfg: MorpheusConfig, pt, *, interpret: bool | None = None
-               ) -> Stats:
-    """Pallas twin of ``core.engine._run_packed``: PackedTraces -> Stats
-    with (B,) leaves.  Jit-safe; ``interpret`` defaults to True off-TPU."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b = pt.warmup.shape[0]
-    ints = jnp.zeros((b, _NI), jnp.int32)
-    flts = jnp.zeros((b, _NF), jnp.float32)
-    warm = pt.warmup[:, None, None]
-    if pt.conv_tag.shape[1] and pt.conv_tag.shape[2]:
-        mask = (pt.conv_active & (pt.conv_pos >= warm)).astype(jnp.int32)
-        iv, fv = conv_scan(cfg, pt.conv_tag.astype(jnp.uint32),
-                           pt.conv_write.astype(jnp.int32),
-                           pt.conv_active.astype(jnp.int32), mask,
-                           interpret=interpret)
-        ints = ints + iv.sum(axis=1)
-        flts = flts + fv.sum(axis=1)
-    if pt.ext_tag.shape[1] and pt.ext_tag.shape[2]:
-        mask = (pt.ext_active & (pt.ext_pos >= warm)).astype(jnp.int32)
-        iv, fv = ext_scan(cfg, pt.ext_tag.astype(jnp.uint32),
-                          pt.ext_write.astype(jnp.int32),
-                          pt.ext_level.astype(jnp.int32),
-                          pt.ext_active.astype(jnp.int32), mask,
-                          interpret=interpret)
-        ints = ints + iv.sum(axis=1)
-        flts = flts + fv.sum(axis=1)
-    return _vecs_to_stats(ints, flts)
+        name=f"engine_scan_{slot.__name__}",
+    )(*cols_k, *rows_k)
+    new_rows = []
+    for x, y in zip(rows, new):
+        y = jnp.swapaxes(y, 1, 2) if x.ndim == 3 else y[:, 0, :]
+        new_rows.append(y != 0 if x.dtype == jnp.bool_ else y)
+    ints, flts = ints.sum(axis=2), flts.sum(axis=2)
+    stats = Stats(**{f: ints[:, i] for i, f in enumerate(INT_FIELDS)},
+                  **{f: flts[:, i] for i, f in enumerate(FLOAT_FIELDS)})
+    return type(rows)(*new_rows), stats

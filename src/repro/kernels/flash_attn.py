@@ -38,11 +38,6 @@ NEG = -2.0e38
 Q_BLOCK = 128
 K_BLOCK = 128
 
-# JAX renamed TPUCompilerParams -> CompilerParams across releases; resolve
-# whichever this installation provides so the kernel lowers on both APIs.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   causal: bool, window: int, softcap: float, scale: float,
@@ -101,7 +96,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale: float | None = None,
                     q_block: int = Q_BLOCK, k_block: int = K_BLOCK,
-                    interpret: bool = True):
+                    interpret: bool):
     """q (B, S, H, hd), k (B, T, KV, hd), v (B, T, KV, hdv)
     -> (B, S, H, hdv).  H = KV * G (grouped-query attention)."""
     b, s, h, hd = q.shape
@@ -144,7 +139,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((g, q_block), jnp.float32),
             pltpu.VMEM((g, q_block, hdv), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

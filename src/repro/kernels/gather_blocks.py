@@ -24,32 +24,29 @@ SET_BLOCK = 64
 
 
 def _gather_kernel(way_ref, data_ref, out_ref):
-    data = data_ref[...]                       # (SB, W, words) uint32
-    way = way_ref[...]                         # (SB,) int32
-    w_iota = jax.lax.broadcasted_iota(jnp.int32, data.shape[:2], 1)
-    onehot = (w_iota == way[:, None])          # (SB, W)
-    # one-hot select over ways (VPU select + OR-reduce; rows are disjoint
-    # so OR == select — exact for uint32 payloads)
-    sel = jnp.where(onehot[..., None], data, jnp.uint32(0))
-    out = sel[:, 0]
-    for i in range(1, sel.shape[1]):
-        out = out | sel[:, i]
+    way = way_ref[...]                         # (SB, 1) int32
+    # one-hot select over ways (VPU select + OR; rows are disjoint so OR ==
+    # select — exact for uint32 payloads).  ``data_ref[:, i]`` is a strided
+    # sublane load of way i of every set.
+    out = jnp.zeros(out_ref.shape, jnp.uint32)
+    for i in range(data_ref.shape[1]):
+        out = out | jnp.where(way == i, data_ref[:, i, :], jnp.uint32(0))
     out_ref[...] = out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_blocks(data: jnp.ndarray, way: jnp.ndarray, *,
-                  interpret: bool = True):
-    """data (S, W, words) u32; way (S,) i32 -> (S, words) u32."""
+def gather_blocks(data: jnp.ndarray, way: jnp.ndarray, *, interpret: bool):
+    """data (S, W, words) u32; way (S,) i32 -> (S, words) u32.  ``S`` is a
+    multiple of 8 up to SET_BLOCK, of SET_BLOCK beyond (``ops`` pads)."""
     s, w, words = data.shape
     sb = min(SET_BLOCK, s)
     assert s % sb == 0, (s, sb)
     return pl.pallas_call(
         _gather_kernel,
         grid=(s // sb,),
-        in_specs=[pl.BlockSpec((sb,), lambda i: (i,)),
+        in_specs=[pl.BlockSpec((sb, 1), lambda i: (i, 0)),
                   pl.BlockSpec((sb, w, words), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((sb, words), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((s, words), jnp.uint32),
         interpret=interpret,
-    )(way, data)
+    )(way[:, None].astype(jnp.int32), data)

@@ -1,9 +1,6 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# --- everything below may import jax -------------------------------------
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -187,6 +184,9 @@ def run_cells(cells, *, multi_pod: bool, out_dir: Path, tag: str = ""):
 
 
 def main():
+    # the production meshes need 512 devices: forced host devices, set
+    # before the first jax computation
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -38,4 +38,7 @@ def make_fleet_mesh(max_devices: int | None = None):
     if max_devices is not None:
         n = min(n, max_devices)
     n = 1 << (n.bit_length() - 1)       # largest pow2 <= n
-    return jax.make_mesh((n,), ("fleet",))
+    # Auto axes: the fleet step slices replica rows out of the sharded
+    # output, which Explicit (sharding-in-types) axes refuse
+    return jax.make_mesh((n,), ("fleet",),
+                         axis_types=(jax.sharding.AxisType.Auto,))

@@ -48,7 +48,9 @@ import os
 import time
 
 
-def main() -> None:
+def main(argv=None):
+    """Parse ``argv`` (default: the command line) and serve; host mode
+    returns the serving ``Engine`` for in-process callers."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
     ap.add_argument("--batch", type=int, default=4)
@@ -109,7 +111,9 @@ def main() -> None:
                     help="attach the pool's block-level event recorder "
                          "(lookup/insert/evict ring) and export it as a "
                          "corpus .npz here on exit")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro import obs
     if args.trace_out or args.metrics_out or args.inspect_out:
@@ -133,7 +137,7 @@ def main() -> None:
             print("NOTE: production-mesh serving requires real hosts; the "
                   "sharded serve_step compiled successfully.")
         _save_obs(args)
-        return
+        return None
 
     import jax
 
@@ -151,6 +155,8 @@ def main() -> None:
                  "are what it apportions under overload)")
 
     cfg = configs.get(args.arch).reduced()
+    print(f"model: {cfg.name} at reduced widths (d_model {cfg.d_model}, "
+          f"{cfg.num_layers} layers, vocab {cfg.vocab_size})")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     pool = governor = None
@@ -336,6 +342,7 @@ def main() -> None:
         print(f"record-trace: {p} (" + " ".join(
             f"{k}:{v}" for k, v in c.items()) + ")")
     _save_obs(args)
+    return eng
 
 
 def _save_obs(args) -> None:

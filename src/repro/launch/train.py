@@ -46,6 +46,8 @@ def main() -> None:
                     help="pod mode: lower+compile only, print roofline")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.mesh != "host":
         # pod path — same lowering as the multi-pod dry-run deliverable

@@ -197,7 +197,7 @@ def _moe_mlp_shardmap(p: dict, x: Array, cfg: ArchConfig, mesh) -> Array:
                                       m * e_local, e_local)
         return jax.lax.psum(y_partial, "model")
 
-    y = dist_ctx.shard_map(
+    y = jax.shard_map(
         per_chip, mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), tok_spec),

@@ -265,12 +265,9 @@ def _install_compile_hook() -> None:
     global _HOOK_INSTALLED
     if _HOOK_INSTALLED:
         return
-    try:
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(_on_event_duration)
-        _HOOK_INSTALLED = True
-    except Exception:            # pragma: no cover - jax-less environment
-        pass
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_event_duration)
+    _HOOK_INSTALLED = True
 
 
 # --------------------------------------------------------- bench counters

@@ -48,7 +48,6 @@ import numpy as np
 from .. import obs
 from ..core import cache_sim as cs
 from ..core import engine
-from ..distributed.context import shard_map
 from ..distributed.sharding import FLEET_AXIS, fleet_padding, fleet_spec
 from .governor import GovernorConfig, OnlineReplica, OnlineResult
 from .telemetry import EpochRecord, TelemetryLog, merge_logs
@@ -200,9 +199,10 @@ def _group_step(cfg, backend: str, mesh, rows: Tuple[int, ...], pad: int):
     def inner(pt, state):
         return engine._run_packed_state(cfg, pt, state, backend)
     if mesh is not None and dict(mesh.shape).get(FLEET_AXIS, 1) > 1:
-        inner = shard_map(inner, mesh=mesh,
-                          in_specs=(fleet_spec(), fleet_spec()),
-                          out_specs=(fleet_spec(), fleet_spec()))
+        inner = jax.shard_map(inner, mesh=mesh,
+                              in_specs=(fleet_spec(), fleet_spec()),
+                              out_specs=(fleet_spec(), fleet_spec()),
+                              check_vma=False)
 
     def step(states, pt):
         state = states[0] if len(states) == 1 else \

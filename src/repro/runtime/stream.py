@@ -487,8 +487,8 @@ def _rebuild_bf1(tags: np.ndarray, sets: np.ndarray, n_sets: int,
     bf1 = np.zeros((n_sets, words), np.uint32)
     if len(tags) == 0:
         return bf1
-    bits = np.asarray(bloomlib._hash_bits(jnp.asarray(tags, jnp.uint32),
-                                          words * 32))          # (N, k)
+    bits = np.stack(bloomlib._hash_bits(jnp.asarray(tags, jnp.uint32),
+                                        words * 32), axis=-1)   # (N, k)
     word_idx = bits // 32
     masks = (np.uint32(1) << (bits % 32).astype(np.uint32))
     rows = np.repeat(sets, bits.shape[1])

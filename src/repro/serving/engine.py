@@ -57,6 +57,7 @@ class EngineReport:
     modeled_time_ns: float
     pred_miss: int
     false_pos: int
+    pages_mismatched: int = 0    # hit pages whose payload read back wrong
 
 
 class Engine:
@@ -75,6 +76,7 @@ class Engine:
         self._decode = jax.jit(model.decode_step)
         self.pages_reused = 0
         self.pages_fetched = 0
+        self.pages_mismatched = 0
 
     # ------------------------------------------------------------- serving
     def run(self, requests: List[Request]) -> EngineReport:
@@ -97,21 +99,26 @@ class Engine:
                     # attribute resident pages back to tenants
                     ins.note_owner(key, r.tenant)
                 plan = self.pool.lookup_batch(np.asarray([key], np.uint32))
+                # the page payload is a digest of its prefix: 128 bytes =
+                # two 64-byte salted blake2b digests (blake2b caps
+                # digest_size at 64)
+                raw = bytes(prefix.__repr__(), "utf8")
+                digest = (hashlib.blake2b(raw, digest_size=64,
+                                          salt=b"pg0").digest() +
+                          hashlib.blake2b(raw, digest_size=64,
+                                          salt=b"pg1").digest())
+                payload = np.frombuffer(digest, dtype=np.uint32)
                 if plan.tier[0] == 2:
+                    # backing fetch = recompute; install the payload
                     self.pages_fetched += 1
-                    # backing fetch = recompute; install a payload digest
-                    raw = bytes(prefix.__repr__(), "utf8")
-                    # 128-byte page payload = two 64-byte salted blake2b
-                    # digests (blake2b caps digest_size at 64).
-                    digest = (hashlib.blake2b(raw, digest_size=64,
-                                              salt=b"pg0").digest() +
-                              hashlib.blake2b(raw, digest_size=64,
-                                              salt=b"pg1").digest())
-                    payload = jnp.asarray(
-                        np.frombuffer(digest, dtype=np.uint32), jnp.uint32)
-                    self.pool.write_page(key, payload)
+                    self.pool.write_page(key, jnp.asarray(payload))
                 else:
+                    # a hit reads its page through the data-array path
+                    # (Indirect-MOV gather, BDI decompress-on-read)
                     self.pages_reused += 1
+                    got = np.asarray(self.pool.read_pages(plan))[0]
+                    self.pages_mismatched += int(
+                        not np.array_equal(got, payload))
 
         # ---- real prefill + decode (the compiled model path)
         tokens = jnp.asarray([r.prompt for r in requests], jnp.int32)
@@ -148,4 +155,5 @@ class Engine:
             modeled_time_ns=st.time_ns,
             pred_miss=st.ext_pred_miss,
             false_pos=st.ext_false_pos,
+            pages_mismatched=self.pages_mismatched,
         )

@@ -8,6 +8,7 @@ order (<= 1e-3 relative) on the float sums.
 import itertools
 import zlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -188,6 +189,24 @@ def test_backend_resolution():
     assert b in engine.BACKENDS and engine.backend_status(b)[0]
     with pytest.raises(engine.BackendError, match="unknown backend"):
         engine.resolve_backend("cuda")
+
+
+def test_platform_decides_interpret_and_backend(monkeypatch):
+    """One resolver, from the platform: kernels interpret only on the CPU
+    backend; on a TPU they compile and the engine defaults to Pallas; on
+    a platform with no Pallas TPU lowering the Pallas engine is reported
+    unavailable instead of replaced."""
+    from repro.kernels import engine_scan, ops
+    monkeypatch.delenv("REPRO_ENGINE_BACKEND", raising=False)
+    assert ops.interpret_mode() is True          # the tests' CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode() is False
+    assert engine.default_backend() == "pallas"
+    assert engine_scan.supported() == (True, "compiled Mosaic kernel")
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert engine_scan.supported()[0] is False
+    with pytest.raises(engine.BackendError, match="unavailable"):
+        engine.resolve_backend("pallas")
 
 
 # ------------------------------------------------------- pack edge cases
