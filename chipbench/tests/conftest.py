@@ -1,0 +1,8 @@
+"""The benchmark's tests run on the CPU: ``python -m pytest chipbench/tests``."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
