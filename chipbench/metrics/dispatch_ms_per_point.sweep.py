@@ -1,0 +1,11 @@
+"""Host time of the engine's dispatch (span ``engine.dispatch``: the
+transfer of the packed arrays and the launch) per sweep point."""
+
+
+def read(ctx):
+    # a CPU backend runs the "device" phases on the host: read only where
+    # the trace saw a device
+    if not ctx.trace or ctx.trace.busy_s <= 0:
+        return None
+    s = ctx.span("engine.dispatch")
+    return 1e3 * s.total_s / ctx.work["points"] if s.count else None

@@ -22,6 +22,7 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Dict, List, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from . import address_separation as asep
@@ -323,16 +324,22 @@ def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
     This is the sweep primitive everything else (``run``, the mode-split
     policy, the benchmark figures) is built on: larger grids, multi-seed
     error bars and online mode-split search are all one ``run_batch``.
-    """
-    prepped = [_prepare(pt) for pt in points]
-    groups: Dict[tuple, List[int]] = {}
-    for i, (cfg, _, _, _, _) in enumerate(prepped):
-        backend = engine.resolve_backend(points[i].backend or None)
-        groups.setdefault((cfg, backend), []).append(i)
 
+    Spans: ``cache_sim.run_batch`` around the whole call, and its phases
+    ``cache_sim.prepare`` (trace generation and configs), per dispatch
+    ``engine.pack`` and ``engine.dispatch`` (in ``simulate_batch``),
+    ``cache_sim.wait`` (the host blocked on the device) and
+    ``cache_sim.unpack`` (per-point Stats and ``_finalize``).
+    """
     results: List[RunResult] = [None] * len(points)  # type: ignore
-    with obs.span("cache_sim.run_batch", points=len(points),
-                  groups=len(groups)):
+    with obs.span("cache_sim.run_batch", points=len(points)) as sp:
+        with obs.span("cache_sim.prepare"):
+            prepped = [_prepare(pt) for pt in points]
+        groups: Dict[tuple, List[int]] = {}
+        for i, (cfg, _, _, _, _) in enumerate(prepped):
+            backend = engine.resolve_backend(points[i].backend or None)
+            groups.setdefault((cfg, backend), []).append(i)
+        sp.set(groups=len(groups))
         for (cfg, backend), idxs in groups.items():
             done = 0
             for blen in _chunk_lengths(len(idxs)):
@@ -342,11 +349,14 @@ def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
                 while len(traces) < blen:     # pad to the compiled shape
                     traces.append(traces[-1])
                 stats_b = engine.simulate_batch(cfg, traces, backend)
-                for j, i in enumerate(chunk):
-                    stats = Stats(*[np.asarray(x[j]) for x in stats_b])
-                    _, _, n_compute, n_cache, n_acc = prepped[i]
-                    results[i] = _finalize(points[i], n_compute, n_cache,
-                                           n_acc, stats)
+                with obs.span("cache_sim.wait"):
+                    jax.block_until_ready(stats_b)
+                with obs.span("cache_sim.unpack"):
+                    for j, i in enumerate(chunk):
+                        stats = Stats(*[np.asarray(x[j]) for x in stats_b])
+                        _, _, n_compute, n_cache, n_acc = prepped[i]
+                        results[i] = _finalize(points[i], n_compute, n_cache,
+                                               n_acc, stats)
     return results
 
 

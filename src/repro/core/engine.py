@@ -175,7 +175,22 @@ def pack(cfg: MorpheusConfig,
     This is how the workload subsystem attributes per-tenant Stats: K
     replays of the same composed stream whose masks partition the
     requests sum to the unmasked run bit-identically on integer counters.
+
+    Span ``engine.pack``; counter ``packed_slots`` counts the padded
+    slots returned, ``B x (Sc x Lc + Se x Le)``: the slots the scan steps
+    through, of which the trace's requests fill the active ones.
     """
+    with obs.span("engine.pack", traces=len(traces)):
+        pt = _pack(cfg, traces, pos0, count)
+    if obs.metrics_on():
+        obs.count("packed_slots", pt.conv_tag.size + pt.ext_tag.size)
+    return pt
+
+
+def _pack(cfg: MorpheusConfig,
+          traces: Sequence[Tuple[np.ndarray, np.ndarray, np.ndarray, int]],
+          pos0: Sequence[int] | None,
+          count: Sequence[np.ndarray | None] | None) -> PackedTraces:
     amap = cfg.amap
     total = max(amap.total_sets, 1)
     sc, se = amap.conv_sets, amap.ext_sets
@@ -436,7 +451,11 @@ def simulate_batch(cfg: MorpheusConfig,
     picks the inner-scan implementation (None -> ``default_backend()``).
     """
     obs.count("engine_dispatches", 1, path="batch")
-    return _run_packed(cfg, pack(cfg, traces), resolve_backend(backend))
+    backend = resolve_backend(backend)
+    pt = pack(cfg, traces)
+    # host side of the dispatch: argument transfer and the launch
+    with obs.span("engine.dispatch"):
+        return _run_packed(cfg, pt, backend)
 
 
 def simulate_parallel(cfg: MorpheusConfig, addrs, writes, levels,

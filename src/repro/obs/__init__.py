@@ -6,7 +6,8 @@ enabling them changes no simulator number, no governor decision, no
 deterministic artifact byte (tests/test_obs.py pins bit-identity):
 
   * ``trace``    — nestable spans -> Chrome/Perfetto trace-event JSON
-    (``obs.span("stream.step", ...)``; null-object fast path when off);
+    (``obs.span("stream.step", ...)``; null-object fast path when off),
+    each mirrored onto the JAX profiler's timeline by its self time;
   * ``metrics``  — process-global counters/gauges/histograms with
     Prometheus text + JSON snapshot export, including a jax compile-hook
     probe counting real XLA compiles;
@@ -20,8 +21,9 @@ environment ``REPRO_OBS=1`` at import.  ``obs.disable()`` drops both;
 the tracer/registry objects stay readable by whoever holds them.
 
 This package imports nothing from the rest of ``repro`` (and jax only
-lazily, inside the compile hook), so every layer — core, runtime,
-workloads, autotune, tools — can instrument itself without cycles.
+lazily, inside the compile hook and ``enable(trace=True)``), so every
+layer — core, runtime, workloads, autotune, tools — can instrument
+itself without cycles.
 """
 from __future__ import annotations
 
@@ -51,11 +53,20 @@ def enable(*, trace: bool = True, metrics: bool = True,
     work the regular span/metric probes never pay."""
     global _TRACER
     if trace and _TRACER is None:
-        _TRACER = Tracer(clock=clock)
+        _TRACER = Tracer(clock=clock, annotate=_profiler_annotation())
     if metrics:
         _metrics.activate()
     if inspect and _inspect.active() is None:
         _inspect.activate(Inspector(every=inspect_every))
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 def disable() -> None:

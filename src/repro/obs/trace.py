@@ -3,10 +3,11 @@
 A ``Tracer`` collects complete ("ph": "X") events from ``span(...)``
 context managers and instant ("ph": "i") events from ``instant(...)``;
 ``to_chrome()``/``save()`` render the standard trace-event envelope that
-``chrome://tracing`` and https://ui.perfetto.dev load directly.  Nesting
-needs no explicit parent links — the viewer reconstructs the stack from
-(ts, dur) containment per (pid, tid) track, and thread ids are mapped to
-small stable ints in first-seen order.
+``chrome://tracing`` and https://ui.perfetto.dev load directly.  The
+tracer keeps a stack of open spans per thread, so every span event
+records its own id and its parent's (``args["id"]``, ``args["parent"]``,
+None at the top); thread ids are mapped to small stable ints in
+first-seen order.
 
 The clock is injectable (``Tracer(clock=...)``, monotonic nanoseconds):
 tests drive a counting clock so exported traces are byte-deterministic,
@@ -14,18 +15,30 @@ and nothing else in the repo's deterministic artifacts (trajectory
 JSONL, telemetry CSV) ever touches a timestamp — the tracer is the only
 place wall-clock time is allowed to appear.
 
+Profiler mirroring: given ``annotate`` (``obs.enable`` binds
+``jax.profiler.TraceAnnotation``), the tracer also puts each span on the
+JAX profiler's timeline, by the span's name only, while it is the
+innermost open span of its thread: opening a child ends the parent's
+profiler event, closing it starts a new one for the parent.  So the
+profiler line holds contiguous, disjoint segments, each span's self
+time, stamped by the profiler's own clock beside the device operations.
+
 When tracing is disabled, instrumentation sites get ``NULL_SPAN`` — one
 shared do-nothing context manager — from ``obs.span``, so a disabled
-span costs one dict build and one identity return (docs/observability.md
-budgets the total at <=2%, gated in CI).
+span costs one dict build and one identity return, and no profiler
+annotation is built.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
+
+#: ``annotate(name)`` returns a profiler event context manager
+Annotate = Callable[[str], object]
 
 
 def _default_clock() -> int:
@@ -36,25 +49,29 @@ class Span:
     """One live span; ``set(**tags)`` injects tags learned mid-span
     (e.g. ``handoff`` only knows its flush count at the end)."""
 
-    __slots__ = ("_tracer", "name", "tags", "_t0")
+    __slots__ = ("_tracer", "name", "tags", "_t0", "id", "parent", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, tags: Dict):
         self._tracer = tracer
         self.name = name
         self.tags = tags
         self._t0 = 0
+        self.id = self.parent = None
+        self._ann = None
 
     def set(self, **tags) -> "Span":
         self.tags.update(tags)
         return self
 
     def __enter__(self) -> "Span":
+        self._tracer._open(self)
         self._t0 = self._tracer._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tracer._complete(self.name, self._t0, self._tracer._clock(),
-                               self.tags)
+        t1 = self._tracer._clock()
+        self._tracer._close(self)
+        self._tracer._complete(self, t1)
         return False
 
 
@@ -80,13 +97,18 @@ NULL_SPAN = _NullSpan()
 
 class Tracer:
     """Collects trace events; thread-safe via the GIL-atomic list append
-    (one tracer is shared by every instrumented site in the process)."""
+    (one tracer is shared by every instrumented site in the process).
+    ``annotate`` mirrors spans onto a profiler timeline (module doc)."""
 
-    def __init__(self, clock: Optional[Callable[[], int]] = None):
+    def __init__(self, clock: Optional[Callable[[], int]] = None,
+                 annotate: Optional[Annotate] = None):
         self._clock = clock if clock is not None else _default_clock
+        self._annotate = annotate
         self.events: List[Dict] = []
         self._tids: Dict[int, int] = {}
         self._lock = threading.Lock()
+        self._ids = itertools.count()
+        self._local = threading.local()
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -96,14 +118,52 @@ class Tracer:
                 tid = self._tids.setdefault(ident, len(self._tids))
         return tid
 
+    def _stack(self) -> List[Span]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def span(self, name: str, **tags) -> Span:
         return Span(self, name, tags)
 
-    def _complete(self, name: str, t0: int, t1: int, tags: Dict) -> None:
+    def _mark(self, sp: Span) -> None:
+        """Start the profiler event of ``sp``, now the innermost span."""
+        sp._ann = self._annotate(sp.name)
+        sp._ann.__enter__()
+
+    @staticmethod
+    def _unmark(sp: Span) -> None:
+        if sp._ann is not None:
+            sp._ann.__exit__(None, None, None)
+            sp._ann = None
+
+    def _open(self, sp: Span) -> None:
+        stack = self._stack()
+        sp.id = next(self._ids)
+        sp.parent = stack[-1].id if stack else None
+        if self._annotate is not None:
+            if stack:
+                self._unmark(stack[-1])
+            self._mark(sp)
+        stack.append(sp)
+
+    def _close(self, sp: Span) -> None:
+        stack = self._stack()
+        innermost = bool(stack) and stack[-1] is sp
+        if sp in stack:
+            stack.remove(sp)
+        self._unmark(sp)
+        # one closed out of order leaves the innermost span marked
+        if innermost and stack and self._annotate is not None:
+            self._mark(stack[-1])
+
+    def _complete(self, sp: Span, t1: int) -> None:
         self.events.append({
-            "name": name, "ph": "X", "ts": t0 / 1e3,
-            "dur": max(t1 - t0, 0) / 1e3,
-            "pid": 0, "tid": self._tid(), "args": dict(tags)})
+            "name": sp.name, "ph": "X", "ts": sp._t0 / 1e3,
+            "dur": max(t1 - sp._t0, 0) / 1e3,
+            "pid": 0, "tid": self._tid(),
+            "args": {**sp.tags, "id": sp.id, "parent": sp.parent}})
 
     def instant(self, name: str, **args) -> None:
         self.events.append({

@@ -215,10 +215,6 @@ class EpochStream:
         return len(self.addrs)
 
     def _pack_epoch(self, lo: int, hi: int) -> PackedTraces:
-        with obs.span("stream.pack", lo=lo, hi=hi):
-            return self._pack_epoch_inner(lo, hi)
-
-    def _pack_epoch_inner(self, lo: int, hi: int) -> PackedTraces:
         k = len(self._masks)
         sl = slice(lo, hi)
         traces = [(self.addrs[sl], self.writes[sl], self.levels[sl],

@@ -13,6 +13,10 @@ Headline properties (acceptance):
     exactly one attributed switch event (the audit invariant);
   * the autotuner's trajectory bytes don't change with obs enabled
     (the golden CRC guarantee extends under instrumentation);
+  * spans reach the JAX profiler's timeline as disjoint, contiguous
+    segments named after the innermost open span (self time), and none
+    are built with tracing off; ``run_batch``'s five phase spans cover
+    the call, and its results are bit-identical with obs on or off;
   * ``TelemetryLog`` exports oldest -> newest even after the ring wraps;
   * bench documents round-trip schema v2 (optional ``counters``) while
     v1 files stay valid; ``tools/obs_report.py`` renders a bundle.
@@ -28,6 +32,7 @@ import pytest
 
 from repro import obs
 from repro.autotune import Tuner, gov_space, make_agent
+from repro.core import cache_sim as cs
 from repro.core import engine
 from repro.obs.decision import TRIGGERS, DecisionEvent
 from repro.obs.metrics import Registry
@@ -90,8 +95,8 @@ def test_tracer_deterministic_with_injected_clock():
     # t1=3000 ns -> microseconds
     assert (inner["ts"], inner["dur"]) == (1.0, 1.0)
     assert (outer["ts"], outer["dur"]) == (0.0, 3.0)
-    assert inner["args"] == {"rows": 4}
-    assert outer["args"] == {"layer": "runtime"}
+    assert inner["args"] == {"rows": 4, "id": 1, "parent": 0}
+    assert outer["args"] == {"layer": "runtime", "id": 0, "parent": None}
 
 
 def test_tracer_instant_and_summary(tmp_path):
@@ -106,6 +111,186 @@ def test_tracer_instant_and_summary(tmp_path):
     assert s["s"]["count"] == 1 and s["s"]["total_us"] == 1.0
     p = t.save(tmp_path / "trace.json")
     assert "traceEvents" in json.loads(p.read_text())
+
+
+class _Annotations:
+    """A fake profiler annotator: logs each event's begin and end."""
+
+    def __init__(self):
+        self.log = []
+        outer = self
+
+        class _Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                outer.log.append(("B", self.name))
+
+            def __exit__(self, *exc):
+                outer.log.append(("E", self.name))
+        self.cls = _Ann
+
+    def segments(self):
+        """Profiler segments in order; asserts they are disjoint (each
+        ends before the next begins) and contiguous (the next begins right
+        after, nothing runs unannotated in between)."""
+        assert [k for k, _ in self.log] == ["B", "E"] * (len(self.log) // 2)
+        begins, ends = self.log[0::2], self.log[1::2]
+        assert [n for _, n in begins] == [n for _, n in ends]
+        return [n for _, n in begins]
+
+
+def test_profiler_segments_are_innermost_self_time():
+    ann = _Annotations()
+    t = Tracer(annotate=ann.cls)
+    with t.span("outer", k=1):
+        with t.span("a"):
+            pass
+        with t.span("b"):
+            with t.span("c"):
+                pass
+    assert ann.segments() == ["outer", "a", "outer", "b", "c", "b", "outer"]
+
+
+def test_span_events_carry_id_and_parent():
+    t = Tracer()
+    with t.span("outer"):
+        with t.span("a"):
+            pass
+        with t.span("b"):
+            with t.span("c"):
+                pass
+    ev = {e["name"]: e["args"] for e in t.events}
+    assert len({a["id"] for a in ev.values()}) == 4
+    assert ev["outer"]["parent"] is None
+    assert ev["a"]["parent"] == ev["b"]["parent"] == ev["outer"]["id"]
+    assert ev["c"]["parent"] == ev["b"]["id"]
+
+
+def test_span_closed_out_of_order_keeps_innermost_marked():
+    ann = _Annotations()
+    t = Tracer(annotate=ann.cls)
+    outer = t.span("outer").__enter__()
+    inner = t.span("inner").__enter__()
+    outer.__exit__(None, None, None)      # closes while a child is open
+    inner.__exit__(None, None, None)
+    assert ann.segments() == ["outer", "inner"]
+    assert t._stack() == []
+
+
+# ------------------------------------------------- phase spans in run_batch
+
+PHASES = ("cache_sim.prepare", "engine.pack", "engine.dispatch",
+          "cache_sim.wait", "cache_sim.unpack")
+
+
+def _tiny_points(backend=""):
+    """Two configurations: a padded chunk of three BL points and one
+    Morpheus-ALL point, so two dispatches."""
+    return [cs.RunPoint("cfd", "BL", n, 0, 3000, backend=backend)
+            for n in (10, 14, 18)] + \
+        [cs.RunPoint("cfd", "Morpheus-ALL", 32, 24, 3000, backend=backend)]
+
+
+def test_tracing_off_builds_no_annotation(monkeypatch):
+    import jax
+    made = []
+
+    class Counting:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    pts = _tiny_points()
+    cs.run_batch(pts)                       # all off
+    obs.enable(trace=False)                 # counters only
+    cs.run_batch(pts)
+    assert made == []
+    obs.enable()                            # spans bind the annotator
+    cs.run_batch(pts)
+    assert "engine.pack" in made and "cache_sim.run_batch" in made
+
+
+def test_run_batch_phase_spans_cover_the_call():
+    pts = _tiny_points()
+    cs.run_batch(pts)                       # compile outside the record
+    obs.enable()
+    cs.run_batch(pts)
+    dispatches = obs.metrics_registry().get("engine_dispatches").total()
+    assert dispatches == 2
+    spans = [e for e in obs.tracer().events if e["ph"] == "X"]
+    top, = [e for e in spans if e["name"] == "cache_sim.run_batch"]
+    assert top["args"]["points"] == 4 and top["args"]["groups"] == 2
+    phases = [e for e in spans if e["name"] in PHASES]
+    names = [e["name"] for e in phases]
+    assert names.count("cache_sim.prepare") == 1
+    for name in PHASES[1:]:
+        assert names.count(name) == dispatches, name
+    assert len(phases) == len(spans) - 1
+    assert all(e["args"]["parent"] == top["args"]["id"] for e in phases)
+    lo, hi = top["ts"], top["ts"] + top["dur"]
+    iv = sorted((e["ts"], e["ts"] + e["dur"]) for e in phases)
+    assert lo <= iv[0][0] and iv[-1][1] <= hi
+    assert all(a[1] <= b[0] for a, b in zip(iv, iv[1:])), "overlap"
+    assert sum(e["dur"] for e in phases) >= 0.9 * top["dur"]
+
+
+def test_packed_slots_counts_padded_slots():
+    obs.enable(trace=False)
+    cfg, trace, *_ = cs._prepare(cs.RunPoint("cfd", "Morpheus-ALL", 32, 24,
+                                             3000))
+    pt = engine.pack(cfg, [trace, trace, trace])
+    b, sc, lc = pt.conv_tag.shape
+    _, se, le = pt.ext_tag.shape
+    assert b == 3 and sc * lc and se * le
+    assert obs.metrics_registry().get("packed_slots").total() == \
+        b * (sc * lc + se * le)
+    assert 3 * len(trace[0]) == pt.conv_active.sum() + pt.ext_active.sum()
+
+
+@pytest.mark.parametrize("backend", [
+    "jnp",
+    pytest.param("pallas", marks=pytest.mark.skipif(
+        not _pallas_ok, reason=_pallas_why)),
+])
+def test_run_batch_bit_identical_under_obs(backend):
+    pts = _tiny_points(backend)
+    base = cs.run_batch(pts)
+    obs.enable()
+    on = cs.run_batch(pts)
+    obs.disable()
+    for a, b in zip(base, on):
+        for f in a.stats._fields:
+            x, y = np.asarray(getattr(a.stats, f)), \
+                np.asarray(getattr(b.stats, f))
+            assert x.dtype == y.dtype and np.array_equal(x, y), f
+        assert (a.exec_time_s, a.ipc) == (b.exec_time_s, b.ipc)
+
+
+def test_profiler_trace_holds_host_phase_events(tmp_path):
+    import jax
+    pts = _tiny_points()
+    cs.run_batch(pts)
+    obs.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        cs.run_batch(pts)
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    names = {e.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"engine.pack", "cache_sim.unpack"} <= names
 
 
 # ---------------------------------------------------------------- metrics

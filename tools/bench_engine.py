@@ -14,10 +14,7 @@ Morpheus-ALL over all 17 workloads) three ways:
   PYTHONPATH=src python tools/bench_engine.py [quick|std|full] [backend ...]
 
 Optional ``backend`` args restrict the batched paths (default: every
-backend supported on this host).  ``--obs-gate`` instead measures the
-observability layer's overhead on the warm batched sweep (obs off vs.
-fully on, interleaved in-process) and writes a bench-document pair for
-``bench_compare --threshold`` — CI's obs job runs it.  The selected backends are printed up
+backend supported on this host).  The selected backends are printed up
 front; requesting an unsupported one fails with a one-line explanation,
 not a Pallas traceback.  Prints a table (path, wall-clock, speedup); the
 result table is recorded in CHANGES.md.
@@ -37,9 +34,7 @@ _args = sys.argv[1:]
 PROFILE = _args[0] if _args and _args[0] in ("quick", "std", "full") \
     else "std"
 os.environ["REPRO_BENCH_PROFILE"] = PROFILE
-OBS_GATE = "--obs-gate" in _args
-REQUESTED = [a for a in _args
-             if a not in ("quick", "std", "full", "--obs-gate")]
+REQUESTED = [a for a in _args if a not in ("quick", "std", "full")]
 
 from repro import obs                            # noqa: E402
 from repro.core import cache_sim as cs           # noqa: E402
@@ -113,50 +108,7 @@ def pick_backends():
     return out
 
 
-def obs_gate():
-    """Measure full-observability overhead on the warm batched sweep.
-
-    Writes two bench documents (``BENCH_engine_obs_base.json`` /
-    ``BENCH_engine_obs_full.json`` next to the committed baselines, or
-    under ``REPRO_BENCH_PATH`` used as a directory) for
-    ``bench_compare --threshold 0.02`` to gate.  Disabled and enabled
-    reps are *interleaved in one process* and each side takes its best
-    rep — two independent bench processes differ by far more than 2%
-    from host noise alone, which would gate nothing."""
-    backend = pick_backends()[0]
-    pts = [replace(pt, backend=backend) for pt in sweep_points()]
-    print(f"obs-gate profile={PROFILE} backend={backend} "
-          f"points={len(pts)}")
-    obs.disable()
-    cs.run_batch(pts)                               # cold / compile
-    t_off, t_on = float("inf"), float("inf")
-    for _ in range(5):
-        obs.disable()
-        t0 = time.time()
-        cs.run_batch(pts)
-        t_off = min(t_off, time.time() - t0)
-        obs.enable(inspect=True)                    # spans + metrics + microscope
-        t0 = time.time()
-        cs.run_batch(pts)
-        t_on = min(t_on, time.time() - t0)
-    obs.disable()
-    print(f"run_batch[{backend}] warm: obs off {t_off:.2f}s / "
-          f"on {t_on:.2f}s ({t_on / t_off - 1.0:+.1%})")
-    outdir = Path(os.environ.pop("REPRO_BENCH_PATH", bs.ROOT))
-    outdir.mkdir(parents=True, exist_ok=True)
-    for tag, secs in (("base", t_off), ("full", t_on)):
-        p = bs.write_bench("engine_obs", PROFILE,
-                           {f"run_batch[{backend}] warm": secs},
-                           extra={"backend": backend, "points": len(pts),
-                                  "obs": tag, "reps": 5},
-                           path=outdir / f"BENCH_engine_obs_{tag}.json")
-        print(f"wrote {p}")
-
-
 def main():
-    if OBS_GATE:
-        obs_gate()
-        return
     # metrics-only (no spans): the counters land in the bench document,
     # while the committed timings stay free of span-recording overhead
     obs.enable(trace=False)
@@ -177,7 +129,7 @@ def main():
         rb = cs.run_batch(bpts)
         timings[f"run_batch[{b}] cold+jit"] = (time.time() - t0, rb)
         # warm = best of 3: single-shot wall-clock on a shared host is
-        # too noisy for the CI overhead gate's 2% threshold
+        # too noisy to compare
         best = float("inf")
         for _ in range(3):
             t0 = time.time()

@@ -19,9 +19,8 @@ compiles, device_get bytes, flush writebacks, epochs) — so a perf diff
 can distinguish "same work, slower" from "more dispatches".  v1 files
 (no ``counters``) stay valid; ``bench_compare --validate`` accepts both.
 
-``REPRO_BENCH_PATH`` redirects ``write_bench``'s default output — CI's
-overhead gate writes throwaway documents without touching the committed
-baselines.
+``REPRO_BENCH_PATH`` redirects ``write_bench``'s default output, so a
+throwaway document leaves the committed baselines untouched.
 """
 from __future__ import annotations
 
