@@ -329,7 +329,9 @@ def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
     ``cache_sim.prepare`` (trace generation and configs), per dispatch
     ``engine.pack`` and ``engine.dispatch`` (in ``simulate_batch``),
     ``cache_sim.wait`` (the host blocked on the device) and
-    ``cache_sim.unpack`` (per-point Stats and ``_finalize``).
+    ``cache_sim.unpack`` (one ``jax.device_get`` of the dispatch's Stats,
+    then per-point Stats sliced from the host arrays and ``_finalize``).
+    Counter: ``stats_readbacks{path="batch"}``, one per dispatch.
     """
     results: List[RunResult] = [None] * len(points)  # type: ignore
     with obs.span("cache_sim.run_batch", points=len(points)) as sp:
@@ -352,8 +354,16 @@ def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
                 with obs.span("cache_sim.wait"):
                     jax.block_until_ready(stats_b)
                 with obs.span("cache_sim.unpack"):
+                    # one transfer for the whole (B,) Stats, then rows
+                    # sliced on the host: slicing the device arrays per
+                    # point costs a device op and a sync per field
+                    host = jax.device_get(stats_b)
+                    obs.count("stats_readbacks", 1, path="batch")
+                    if obs.metrics_on():
+                        obs.count("device_get_bytes",
+                                  sum(x.nbytes for x in host))
                     for j, i in enumerate(chunk):
-                        stats = Stats(*[np.asarray(x[j]) for x in stats_b])
+                        stats = Stats(*[np.asarray(x[j]) for x in host])
                         _, _, n_compute, n_cache, n_acc = prepped[i]
                         results[i] = _finalize(points[i], n_compute, n_cache,
                                                n_acc, stats)

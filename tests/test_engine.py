@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import address_separation as asep
 from repro.core import cache_sim as cs
 from repro.core import controller as ctl
@@ -129,6 +130,40 @@ def test_run_batch_padding_chunk():
     res = cs.run_batch(pts)
     assert [r.n_compute for r in res] == [10, 14, 18, 24, 32]
     assert len({r.exec_time_s for r in res}) > 1  # distinct grid points
+
+
+def test_run_batch_one_stats_readback_per_dispatch():
+    """Two configs, one of them a padded chunk: one Stats readback per
+    dispatch, and every per-point field equals the batch's row in value
+    and dtype."""
+    pts = [cs.RunPoint("cfd", "BL", n, 0, 3000) for n in (10, 14, 18)] + \
+        [cs.RunPoint("cfd", "Morpheus-ALL", 32, 24, 3000)]
+    obs.enable(trace=False)
+    try:
+        res = cs.run_batch(pts)
+        reg = obs.metrics_registry()
+        readbacks = reg.get("stats_readbacks").values
+        dispatches = reg.get("engine_dispatches").values
+        got_bytes = reg.get("device_get_bytes").total()
+    finally:
+        obs.disable()
+    key = (("path", "batch"),)
+    assert readbacks[key] == dispatches[key] == 2
+    assert got_bytes > 0
+    prepped = [cs._prepare(pt) for pt in pts]
+    for idxs in ([0, 1, 2], [3]):
+        cfg = prepped[idxs[0]][0]
+        traces = [prepped[i][1] for i in idxs]
+        traces += [traces[-1]] * (engine._bucket(len(idxs), minimum=1)
+                                  - len(idxs))
+        want_b = engine.simulate_batch(cfg, traces)
+        for j, i in enumerate(idxs):
+            for f in ctl.Stats._fields:
+                got = getattr(res[i].stats, f)
+                want = np.asarray(getattr(want_b, f)[j])
+                assert isinstance(got, np.ndarray) and got.shape == ()
+                assert got.dtype == want.dtype, (i, f)
+                assert got == want, (i, f)
 
 
 # ------------------------------------------------------- pallas backend
