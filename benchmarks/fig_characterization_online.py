@@ -69,9 +69,9 @@ def characterize(app: str, *, length: int, epoch_len: int,
     assert snaps, f"{app}: microscope recorded no snapshots"
     # the same stream the run replayed (generate_phased with one phase
     # == generate at the split's core count) — profiled host-side
+    scale = cs.SYSTEMS[SYSTEM].sim_scale
     addrs, _, _ = synthetic.generate(app, n_cores=SPLIT[0], length=length,
-                                     seed=seed,
-                                     ws_scale=1.0 / cs.SIM_SCALE)
+                                     seed=seed, ws_scale=1.0 / scale)
     p = prof.profile_trace(addrs, block_bytes=synthetic.BLOCK_BYTES)
     last = snaps[-1]
     tail = snaps[len(snaps) // 2:]       # steady state: back half
@@ -94,7 +94,7 @@ def characterize(app: str, *, length: int, epoch_len: int,
 def run() -> Dict[str, float]:
     apps = _APPS[C.PROFILE]
     length, epoch_len = _LEN[C.PROFILE], _EPOCH[C.PROFILE]
-    conv_bytes = cs.CONV_LLC_BYTES // cs.SIM_SCALE
+    conv_bytes = cs.CONV_LLC_BYTES // cs.SYSTEMS[SYSTEM].sim_scale
     rows: List[List] = []
     out: Dict[str, float] = {}
     agree: List[bool] = []

@@ -124,7 +124,7 @@ def run() -> Dict[str, float]:
     for churn_name, spec in _CHURNS[C.PROFILE]:
         wl = tenancy.make_workload(spec, length=length, n_cores=N_CORES,
                                    arrival=ARRIVAL, seed=0,
-                                   ws_scale=1.0 / cs.SIM_SCALE)
+                                   ws_scale=1.0 / cs.SYSTEMS[SYSTEM].sim_scale)
         ladder = candidates_for(wl.primary_app, SYSTEM, grid=LADDER_GRID,
                                 length=length)
         bounds = wl.epoch_bounds(epoch_len=tepoch)

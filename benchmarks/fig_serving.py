@@ -84,7 +84,7 @@ def run() -> Dict[str, float]:
             # (repro.autotune.objectives) scores candidates on exactly
             # this construction
             wl = bursty_workload(mix, arr_spec, length=length,
-                                 n_cores=N_CORES, seed=0)
+                                 n_cores=N_CORES, seed=0, system=SYSTEM)
             cv = arrlib.burstiness(wl.t_s)
             ladder = candidates_for(wl.primary_app, SYSTEM,
                                     grid=LADDER_GRID, length=length)

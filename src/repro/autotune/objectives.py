@@ -111,7 +111,8 @@ class GovernorObjective:
         self.seed = int(seed)
         self.backend = backend
         self.workloads = [bursty_workload(mix, arr, length=self.length,
-                                          n_cores=n_cores, seed=self.seed)
+                                          n_cores=n_cores, seed=self.seed,
+                                          system=system)
                           for mix, arr in self.cells]
         self.ladders = [candidates_for(wl.primary_app, system,
                                        grid=tuple(ladder_grid),

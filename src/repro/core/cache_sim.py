@@ -40,9 +40,10 @@ MLP_PER_CORE = 128.0        # outstanding memory requests per SM (48 warps
 #                             x >2 outstanding loads; keeps the latency term
 #                             from masking the bandwidth wall, Fig. 1 knee)
 CONV_LLC_BYTES = 5 * (1 << 20)
-SIM_SCALE = 8               # simulate a 1/8-scale memory system (capacities
-#                             and working sets both scaled; behaviour of a
-#                             set-associative LLC is ~invariant under this)
+SIM_SCALE = 8               # default ``SystemSpec.sim_scale``: a 1/8-scale
+#                             memory system (capacities and working sets both
+#                             scaled; behaviour of a set-associative LLC is
+#                             ~invariant under this)
 CONV_WAYS = 32
 LLC_PARTITIONS = 10
 EXT_BYTES_PER_CORE = 328 * 1024     # §5 'Combining': RF(32w) + L1(16w)
@@ -73,6 +74,7 @@ class SystemSpec:
     predictor: Predictor = Predictor.BLOOM
     mem_boost: float = 1.0           # Frequency-Boost: BW*, 1/latency*
     unified_extra_bytes: int = 0     # Unified-SM-Mem: extra per-core filter
+    sim_scale: int = SIM_SCALE       # capacities and working sets at 1/scale
 
 
 SYSTEMS: Dict[str, SystemSpec] = {
@@ -89,14 +91,19 @@ SYSTEMS: Dict[str, SystemSpec] = {
                                         indirect_mov=True),
     "Morpheus-ALL": SystemSpec("Morpheus-ALL", morpheus=True,
                                compression=True, indirect_mov=True),
+    # the paper's GPU at its published capacity: 1 280 conventional sets,
+    # 82 extended sets per cache-mode core (up to 4 182)
+    "Morpheus-ALL@1": SystemSpec("Morpheus-ALL@1", morpheus=True,
+                                 compression=True, indirect_mov=True,
+                                 sim_scale=1),
 }
 
 
 def build_config(spec: SystemSpec, n_cache: int) -> MorpheusConfig:
-    conv_bytes = int(CONV_LLC_BYTES * spec.conv_scale) // SIM_SCALE
+    conv_bytes = int(CONV_LLC_BYTES * spec.conv_scale) // spec.sim_scale
     conv_sets = max(conv_bytes // (CONV_WAYS * tr.BLOCK_BYTES), 16)
     n_cache = n_cache if spec.morpheus else 0
-    sets_per_chip = max(EXT_SETS_PER_CORE // SIM_SCALE, 2)
+    sets_per_chip = max(EXT_SETS_PER_CORE // spec.sim_scale, 2)
     amap = asep.make_map(conv_sets=conv_sets, num_cache_chips=n_cache,
                          sets_per_chip=sets_per_chip)
     return MorpheusConfig(amap=amap, conv_ways=CONV_WAYS, ext_ways=EXT_WAYS,
@@ -218,7 +225,7 @@ def _prepare(pt: RunPoint):
 
     addrs, writes, levels = tr.generate(pt.app, n_cores=n_compute,
                                         length=pt.length, seed=pt.seed,
-                                        ws_scale=1.0 / SIM_SCALE)
+                                        ws_scale=1.0 / spec.sim_scale)
     if spec.unified_extra_bytes:
         addrs, writes, levels = _unified_filter(addrs, writes, levels,
                                                 n_compute,
@@ -226,7 +233,7 @@ def _prepare(pt: RunPoint):
     cfg = apply_overrides(build_config(spec, n_cache), pt.overrides)
     # exclude the compulsory-miss warmup (one pass over the working set,
     # capped at half the trace) so stats reflect steady state
-    ws_blocks = w.working_set_bytes // SIM_SCALE // tr.BLOCK_BYTES
+    ws_blocks = w.working_set_bytes // spec.sim_scale // tr.BLOCK_BYTES
     warmup = int(min(len(addrs) // 2, ws_blocks))
     return (cfg, (addrs, writes, levels, warmup), n_compute, n_cache,
             len(addrs) - warmup)

@@ -176,15 +176,35 @@ def pack(cfg: MorpheusConfig,
     replays of the same composed stream whose masks partition the
     requests sum to the unmasked run bit-identically on integer counters.
 
+    Each tier's set axis is padded with empty sets (never active) to whole
+    tiles of the Pallas scan (``set_tiling``); a tier one tile holds keeps
+    its set count.
+
     Span ``engine.pack``; counter ``packed_slots`` counts the padded
     slots returned, ``B x (Sc x Lc + Se x Le)``: the slots the scan steps
-    through, of which the trace's requests fill the active ones.
+    through, of which the trace's requests fill the active ones; counter
+    ``tier_requests{tier="conv"|"ext"}`` the requests packed on each tier.
     """
     with obs.span("engine.pack", traces=len(traces)):
-        pt = _pack(cfg, traces, pos0, count)
+        pt, n_conv, n_ext = _pack(cfg, traces, pos0, count)
     if obs.metrics_on():
         obs.count("packed_slots", pt.conv_tag.size + pt.ext_tag.size)
+        obs.count("tier_requests", n_conv, tier="conv")
+        obs.count("tier_requests", n_ext, tier="ext")
     return pt
+
+
+def set_tiling(cfg: MorpheusConfig
+               ) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+    """The Pallas scan's (sets per tile, tiles) of the conventional and
+    the extended tier, from each tier's state rows per set (``ConvRow``:
+    four leaves of ``conv_ways``; ``ExtRow``: five of ``ext_max_ways``,
+    two Bloom filters and two scalars) and request columns
+    (``kernels.engine_scan.set_tiling``)."""
+    from ..kernels import engine_scan
+    ext_rows = 5 * cfg.ext_max_ways + 2 * ctl.BLOOM_WORDS + 2
+    return (engine_scan.set_tiling(cfg.amap.conv_sets, 4 * cfg.conv_ways, 4),
+            engine_scan.set_tiling(cfg.amap.ext_sets, ext_rows, 5))
 
 
 def _pack(cfg: MorpheusConfig,
@@ -194,10 +214,13 @@ def _pack(cfg: MorpheusConfig,
     amap = cfg.amap
     total = max(amap.total_sets, 1)
     sc, se = amap.conv_sets, amap.ext_sets
+    (tc, nc), (te, ne) = set_tiling(cfg)
+    sc_pad, se_pad = tc * nc, te * ne
     prepped = []
-    max_c = max_e = 0
+    max_c = max_e = n_req = n_ext = 0
     for i, (addrs, writes, levels, warmup) in enumerate(traces):
         addrs = np.asarray(addrs, np.uint32)
+        n_req += len(addrs)
         writes = np.asarray(writes, bool)
         levels = np.asarray(levels, np.int32)
         gset = (addrs % np.uint32(total)).astype(np.int64)
@@ -209,6 +232,7 @@ def _pack(cfg: MorpheusConfig,
             assert mask.shape == addrs.shape, "count mask length mismatch"
             pos = np.where(mask, pos, _UNCOUNTED_POS)
         is_ext = gset >= sc if cfg.ext_enabled else np.zeros(len(addrs), bool)
+        n_ext += int(is_ext.sum())
         if sc:
             cnt = np.bincount(gset[~is_ext], minlength=sc)
             max_c = max(max_c, int(cnt.max()) if cnt.size else 0)
@@ -220,9 +244,9 @@ def _pack(cfg: MorpheusConfig,
     lc = _bucket(max_c) if sc and max_c else 0
     le = _bucket(max_e) if se and max_e else 0
     b = len(traces)
-    conv = [np.zeros((b, sc, lc), dt) for dt in
+    conv = [np.zeros((b, sc_pad, lc), dt) for dt in
             (np.uint32, bool, np.int32, bool)]
-    ext = [np.zeros((b, se, le), dt) for dt in
+    ext = [np.zeros((b, se_pad, le), dt) for dt in
            (np.uint32, bool, np.int32, np.int32, bool)]
     warmups = np.zeros((b,), np.int32)
     for i, (gset, tag, pos, is_ext, writes, levels, warmup) in \
@@ -231,17 +255,18 @@ def _pack(cfg: MorpheusConfig,
         if lc:
             keep = ~is_ext
             act, (t, w, p) = _dense_layout(
-                gset[keep], sc, lc, (tag[keep], writes[keep], pos[keep]))
+                gset[keep], sc_pad, lc, (tag[keep], writes[keep], pos[keep]))
             conv[0][i], conv[1][i], conv[2][i], conv[3][i] = t, w, p, act
         if le:
             keep = is_ext
             act, (t, w, l, p) = _dense_layout(
-                gset[keep] - sc, se, le,
+                gset[keep] - sc, se_pad, le,
                 (tag[keep], writes[keep], levels[keep], pos[keep]))
             (ext[0][i], ext[1][i], ext[2][i],
              ext[3][i], ext[4][i]) = t, w, l, p, act
-    return PackedTraces(conv[0], conv[1], conv[2], conv[3],
-                        ext[0], ext[1], ext[2], ext[3], ext[4], warmups)
+    return (PackedTraces(conv[0], conv[1], conv[2], conv[3],
+                         ext[0], ext[1], ext[2], ext[3], ext[4], warmups),
+            n_req - n_ext, n_ext)
 
 
 # ------------------------------------------------------------------ state
@@ -392,23 +417,37 @@ def _run_packed_state(cfg: MorpheusConfig, pt: PackedTraces,
     warm = pt.warmup[:, None, None]
     delta = jax.tree.map(
         lambda z: jnp.zeros((b,) + z.shape, z.dtype), ctl._zero_stats())
+
+    def scan_padded(slot, rows, cols):
+        # the packed set axis may hold empty sets past the config's (whole
+        # scan tiles, ``pack``): their state rows are empty too, and are
+        # dropped again after the scan
+        n, s = rows[0].shape[1], cols[0].shape[1]
+        if s == n:
+            return scan(cfg, slot, rows, cols)
+        rows = jax.tree.map(lambda x: jnp.pad(
+            x, [(0, 0), (0, s - n)] + [(0, 0)] * (x.ndim - 2)), rows)
+        rows, d = scan(cfg, slot, rows, cols)
+        return jax.tree.map(lambda x: x[:, :n], rows), d
+
     if pt.conv_tag.shape[1] and pt.conv_tag.shape[2]:
-        rows, d = scan(cfg, ctl.conv_slot,
-                       ctl.ConvRow(state.conv_tags, state.conv_valid,
-                                   state.conv_dirty, state.conv_lru),
-                       (pt.conv_tag, pt.conv_write, pt.conv_active,
-                        pt.conv_active & (pt.conv_pos >= warm)))
+        rows, d = scan_padded(ctl.conv_slot,
+                              ctl.ConvRow(state.conv_tags, state.conv_valid,
+                                          state.conv_dirty, state.conv_lru),
+                              (pt.conv_tag, pt.conv_write, pt.conv_active,
+                               pt.conv_active & (pt.conv_pos >= warm)))
         delta = jax.tree.map(jnp.add, delta, d)
         state = state._replace(conv_tags=rows.tags, conv_valid=rows.valid,
                                conv_dirty=rows.dirty, conv_lru=rows.lru)
     if pt.ext_tag.shape[1] and pt.ext_tag.shape[2]:
-        rows, d = scan(cfg, ctl.ext_slot,
-                       ctl.ExtRow(state.ext_tags, state.ext_valid,
-                                  state.ext_dirty, state.ext_lru,
-                                  state.ext_size, state.ext_used,
-                                  state.bf1, state.bf2, state.n_mru),
-                       (pt.ext_tag, pt.ext_write, pt.ext_level,
-                        pt.ext_active, pt.ext_active & (pt.ext_pos >= warm)))
+        rows, d = scan_padded(ctl.ext_slot,
+                              ctl.ExtRow(state.ext_tags, state.ext_valid,
+                                         state.ext_dirty, state.ext_lru,
+                                         state.ext_size, state.ext_used,
+                                         state.bf1, state.bf2, state.n_mru),
+                              (pt.ext_tag, pt.ext_write, pt.ext_level,
+                               pt.ext_active,
+                               pt.ext_active & (pt.ext_pos >= warm)))
         delta = jax.tree.map(jnp.add, delta, d)
         state = state._replace(ext_tags=rows.tags, ext_valid=rows.valid,
                                ext_dirty=rows.dirty, ext_lru=rows.lru,
@@ -436,7 +475,21 @@ def advance_packed(cfg: MorpheusConfig, pt: PackedTraces, state: EngineState,
     to a single monolithic ``simulate_batch`` of the concatenated trace.
     """
     obs.count("engine_dispatches", 1, path="epoch")
-    return _run_packed_state(cfg, pt, state, resolve_backend(backend))
+    backend = resolve_backend(backend)
+    _count_set_tiles(cfg, pt, backend)
+    return _run_packed_state(cfg, pt, state, backend)
+
+
+def _count_set_tiles(cfg: MorpheusConfig, pt: PackedTraces,
+                     backend: str) -> None:
+    """Counter ``scan_set_tiles{tier}``: the set tiles each Pallas scan of
+    a dispatch runs over (one per tier a tile holds)."""
+    if backend != "pallas" or not obs.metrics_on():
+        return
+    for tier, (_, tiles), tag in zip(("conv", "ext"), set_tiling(cfg),
+                                     (pt.conv_tag, pt.ext_tag)):
+        if tag.shape[1] and tag.shape[2]:
+            obs.count("scan_set_tiles", tiles, tier=tier)
 
 
 def simulate_batch(cfg: MorpheusConfig,
@@ -453,6 +506,7 @@ def simulate_batch(cfg: MorpheusConfig,
     obs.count("engine_dispatches", 1, path="batch")
     backend = resolve_backend(backend)
     pt = pack(cfg, traces)
+    _count_set_tiles(cfg, pt, backend)
     # host side of the dispatch: argument transfer and the launch
     with obs.span("engine.dispatch"):
         return _run_packed(cfg, pt, backend)

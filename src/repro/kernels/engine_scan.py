@@ -8,15 +8,19 @@ kernel that lives next to the memory it manages.
 
 Layout (sets on lanes, the form Mosaic lowers):
 
-  * grid = (B, L / Lc): one program instance per (trace, chunk of Lc
-    request slots).  The slot axis is sequential ("arbitrary"): chunks of
-    one trace run in order and the state stays resident in VMEM across
-    them.
+  * grid = (B, S / T, L / Lc): one program instance per (trace, tile of T
+    sets, chunk of Lc request slots).  Sets are independent, so the set
+    axis is "parallel"; the slot axis is sequential ("arbitrary"): chunks
+    of one tile run in order and its state stays resident in VMEM across
+    them.  ``set_tiling`` picks T: all S sets where their blocks fit
+    ``BLOCK_BUDGET`` (one tile: every shape of the 1/8-scale systems),
+    else the fewest equal tiles of whole 128-lane groups that fit;
+    ``engine.pack`` pads the set axis to whole tiles with empty sets.
   * in_specs: the packed request columns, transposed to (B, L, S) and
-    tiled (1, Lc, S) — one sublane row per slot, one lane per set.  Slot
-    ``t`` of every set is one dynamic-row load ``col[0, t]``.
-  * state: every row leaf as a (1, ways, S) / (1, words, S) / (1, 1, S)
-    block (one column per set; bools as int32).  Instance 0 of a trace
+    tiled (1, Lc, T) — one sublane row per slot, one lane per set.  Slot
+    ``t`` of every set of the tile is one dynamic-row load ``col[0, t]``.
+  * state: every row leaf as a (1, ways, T) / (1, words, T) / (1, 1, T)
+    block (one column per set; bools as int32).  Instance 0 of a tile
     copies the input state into the output block, which then carries the
     state across the slot chunks.
   * body: ``lax.fori_loop`` over the chunk's slots, applying the SAME
@@ -59,6 +63,41 @@ _NI, _NF = len(INT_FIELDS), len(FLOAT_FIELDS)
 
 # request slots per grid step (the packed L is a power of two)
 SLOT_CHUNK = 128
+# What one kernel instance's blocks may take: half of Mosaic's default
+# scoped VMEM on TPU v5e (16 MiB), whose other half holds the body's
+# temporaries.  The extended tier's slot keeps (ways, T) values there about
+# as large as its blocks: at 768 lanes a v5e compile asks for 16.5 MiB
+# against 11.6 MiB of blocks, and fails; at 640 it fits.
+BLOCK_BUDGET = 8 * 2**20
+LANES = 128
+
+
+def _vmem_bytes(tile: int, state_rows: int, n_cols: int) -> int:
+    """VMEM of one instance's blocks for ``tile`` sets, every block
+    double-buffered: the request columns, the state in and out, and the
+    Stats rows (sublane-padded).  Lanes round up to whole 128-lane
+    groups."""
+    stats = sum(-(-n // 8) * 8 for n in (_NI, _NF))
+    rows = 2 * (n_cols * SLOT_CHUNK + 2 * state_rows + stats)
+    return 4 * rows * (-(-tile // LANES) * LANES)
+
+
+def set_tiling(n_sets: int, state_rows: int, n_cols: int
+               ) -> Tuple[int, int]:
+    """(sets per tile, tiles) of a tier of ``n_sets`` sets whose state is
+    ``state_rows`` int32 rows per set and whose slot takes ``n_cols``
+    request columns: one tile of all sets where their blocks fit
+    ``BLOCK_BUDGET``, else the fewest equal tiles of whole 128-lane groups
+    that do."""
+    if _vmem_bytes(n_sets, state_rows, n_cols) <= BLOCK_BUDGET:
+        return n_sets, 1
+    groups = -(-n_sets // LANES)
+    for tiles in range(2, groups + 1):
+        tile = -(-groups // tiles) * LANES
+        if _vmem_bytes(tile, state_rows, n_cols) <= BLOCK_BUDGET:
+            return tile, -(-n_sets // tile)
+    raise ValueError(f"the blocks of one 128-lane group of a "
+                     f"{state_rows}-row state exceed the VMEM budget")
 
 
 def supported() -> Tuple[bool, str]:
@@ -79,7 +118,7 @@ def _scan_kernel(cfg, slot, row_type, col_bool, row_bool, *refs):
     ints_ref, flts_ref = refs[n_col + n_row:n_col + n_row + 2]
     rows = refs[n_col + n_row + 2:]
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _start():
         for src, dst in zip(rows_in, rows):
             dst[...] = src[...]
@@ -121,11 +160,16 @@ def scan_tier(cfg: ctl.MorpheusConfig, slot, rows, cols, *,
 
     ``slot`` is ``controller.conv_slot`` or ``ext_slot``; ``rows`` its row
     NamedTuple with (B, S, ...) state leaves; ``cols`` the slot's request
-    columns, each (B, S, L), counted mask last.  Returns (final rows,
-    Stats with (B,) leaves)."""
+    columns, each (B, S, L), counted mask last; S is whole tiles of
+    ``set_tiling``.  Returns (final rows, Stats with (B,) leaves)."""
     interpret = ops.interpret_mode() if interpret is None else interpret
     b, s, length = cols[0].shape
     lc = SLOT_CHUNK if length % SLOT_CHUNK == 0 else length
+    state_rows = sum(x.shape[2] if x.ndim == 3 else 1 for x in rows)
+    tile, tiles = set_tiling(s, state_rows, len(cols))
+    if tile * tiles != s:
+        raise ValueError(f"{s} sets are not whole tiles of {tile}: pack "
+                         f"with engine.pack")
     col_bool = tuple(c.dtype == jnp.bool_ for c in cols)
     row_bool = tuple(x.dtype == jnp.bool_ for x in rows)
     cols_k = [jnp.swapaxes(c, 1, 2) for c in cols]
@@ -136,19 +180,19 @@ def scan_tier(cfg: ctl.MorpheusConfig, slot, rows, cols, *,
                   jax.ShapeDtypeStruct((b, _NF, s), jnp.float32)]
                  + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in rows_k])
 
-    def whole(x):
-        return pl.BlockSpec((1,) + tuple(x.shape[1:]), lambda i, j: (i, 0, 0))
+    def sets(x):
+        return pl.BlockSpec((1, x.shape[1], tile), lambda i, k, j: (i, 0, k))
 
     ints, flts, *new = pl.pallas_call(
         functools.partial(_scan_kernel, cfg, slot, type(rows), col_bool,
                           row_bool),
-        grid=(b, length // lc),
-        in_specs=([pl.BlockSpec((1, lc, s), lambda i, j: (i, j, 0))]
-                  * len(cols_k) + [whole(x) for x in rows_k]),
-        out_specs=[whole(o) for o in out_shape],
+        grid=(b, tiles, length // lc),
+        in_specs=([pl.BlockSpec((1, lc, tile), lambda i, k, j: (i, j, k))]
+                  * len(cols_k) + [sets(x) for x in rows_k]),
+        out_specs=[sets(o) for o in out_shape],
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"engine_scan_{slot.__name__}",
     )(*cols_k, *rows_k)

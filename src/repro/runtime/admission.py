@@ -362,7 +362,7 @@ def simulate_overload(tenants: Sequence[TenantSLO],
         "overload tenants need TenantSLO.app trace profiles"
     names = [t.name for t in tenants]
     spec = cs.SYSTEMS[system]
-    ws_scale = 1.0 / cs.SIM_SCALE
+    ws_scale = 1.0 / spec.sim_scale
     schedule = [{n: int(r.get(n, 0)) for n in names} for r in schedule]
     offered_tot = {n: sum(r[n] for r in schedule) for n in names}
 

@@ -901,7 +901,7 @@ class OnlineReplica:
                  name: str = "", slo=None):
         workload = phases if hasattr(phases, "tenants") else None
         spec = cs.SYSTEMS[system]
-        ws_scale = 1.0 / cs.SIM_SCALE
+        ws_scale = 1.0 / spec.sim_scale
         if workload is not None:
             wl = workload
             length = len(wl)
@@ -960,7 +960,7 @@ class OnlineReplica:
         mean_epoch = max(length // max(len(epoch_bounds), 1), 1)
         if burn_in is None:
             ws_blocks = tr.WORKLOADS[primary].working_set_bytes \
-                // cs.SIM_SCALE // tr.BLOCK_BYTES
+                // spec.sim_scale // tr.BLOCK_BYTES
             burn_in = max(1, int(np.ceil(ws_blocks / mean_epoch)))
 
         self.system = system

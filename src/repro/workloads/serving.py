@@ -428,11 +428,12 @@ def round_requests(workload: str, arrival: str, rounds: int,
 
 
 def bursty_workload(mix: str, arrival: str, *, length: int,
-                    n_cores: int = 32, seed: int = 0):
+                    n_cores: int = 32, seed: int = 0,
+                    system: str = "Morpheus-ALL"):
     """One cell of the bursty serving corpus (the fig_serving grid).
 
-    K tenants' traces merged by arrival time at simulator working-set
-    scale — the canonical (mix, arrival) evaluation cell shared by
+    K tenants' traces merged by arrival time at the working-set scale of
+    the simulated ``system`` — the canonical (mix, arrival) evaluation cell shared by
     ``benchmarks/fig_serving`` and the autotuner's governor objective
     (``repro.autotune.objectives``), so a searched ``GovernorConfig`` is
     scored on exactly the corpus the hand-tuned preset was judged on.
@@ -441,6 +442,7 @@ def bursty_workload(mix: str, arrival: str, *, length: int,
     """
     from ..core import cache_sim as cs
     from . import tenancy
+    scale = cs.SYSTEMS[system].sim_scale
     return tenancy.make_workload(mix, length=length, n_cores=n_cores,
                                  arrival=arrival, seed=seed,
-                                 ws_scale=1.0 / cs.SIM_SCALE)
+                                 ws_scale=1.0 / scale)

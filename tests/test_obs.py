@@ -681,9 +681,10 @@ def test_residency_sums_to_valid_blocks_every_epoch():
     account for every valid block in both tiers, every epoch."""
     from repro.core import cache_sim as cs
     from repro.workloads import tenancy
+    scale = cs.SYSTEMS["Morpheus-ALL"].sim_scale
     wl = tenancy.make_workload("cfd,kmeans", length=9_000, n_cores=32,
                                arrival="det:2e6", seed=0,
-                               ws_scale=1.0 / cs.SIM_SCALE)
+                               ws_scale=1.0 / scale)
     obs.enable(trace=False, metrics=False, inspect=True)
     simulate_online(wl, "Morpheus-ALL", epoch_len=1_500)
     snaps = obs.inspector().snapshots
@@ -765,9 +766,10 @@ def test_fairness_column_in_epoch_records():
     assert "fairness" in FIELDS and FIELDS[-1] == "decision"
     r = _online()                    # single tenant: exactly 1.0
     assert all(rec.fairness == 1.0 for rec in r.records)
+    scale = cs.SYSTEMS["Morpheus-ALL"].sim_scale
     wl = tenancy.make_workload("cfd,kmeans", length=9_000, n_cores=32,
                                arrival="det:2e6", seed=0,
-                               ws_scale=1.0 / cs.SIM_SCALE)
+                               ws_scale=1.0 / scale)
     m = simulate_online(wl, "Morpheus-ALL", epoch_len=1_500)
     assert all(0.0 < rec.fairness <= 1.0 for rec in m.records)
 

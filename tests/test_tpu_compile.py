@@ -67,10 +67,13 @@ def _assert_kernel(fn, *avals):
 
 # --------------------------------------------------------- engine scan
 
-# the full benchmark profile's trace length on two systems: Morpheus-ALL
-# (both tiers, compressed extended ways) and the conventional-only baseline
+# the full benchmark profile's trace length on three systems: Morpheus-ALL
+# (both tiers, compressed extended ways), the conventional-only baseline,
+# and Morpheus-ALL at full scale (4 182 extended sets: the set-tiled scan
+# within the default scoped VMEM)
 ENGINE_CELLS = {"morpheus_all": ("kmeans", "Morpheus-ALL", 32, 36),
-                "conv_only": ("kmeans", "BL", 32, 0)}
+                "conv_only": ("kmeans", "BL", 32, 0),
+                "full_scale": ("kmeans", "Morpheus-ALL@1", 17, 51)}
 
 
 def _packed(cell):

@@ -49,7 +49,7 @@ def bench_stream(length: int, epoch_lens, backend: str) -> dict:
     spec = cs.SYSTEMS["Morpheus-ALL"]
     cfg = cs.build_config(spec, 36)
     addrs, writes, levels = tr.generate("cfd", n_cores=32, length=length,
-                                        ws_scale=1.0 / cs.SIM_SCALE)
+                                        ws_scale=1.0 / spec.sim_scale)
     warmup = length // 4
 
     def ints(s):
