@@ -9,10 +9,14 @@ decomposes into thousands of independent per-set state machines.
 
 This module exploits that:
 
-  1. ``pack`` partitions each trace by (tier, set) on the host — a stable
-     sort, so the in-set request order (the only order that matters) is
-     preserved — and lays the per-set subsequences out as padded dense
-     (num_sets, L) arrays with an activity mask.
+  1. ``pack`` partitions each trace by (tier, set) on the host — one
+     stable sort per trace on its global set index (a 16-bit key at
+     every configuration's set count, which numpy sorts by radix), so
+     the in-set request order (the only order that matters) is preserved
+     and, conventional sets being numbered below extended ones, both
+     tiers come out as contiguous runs — and scatters each column once,
+     through a flat index, straight into the padded dense (B, num_sets,
+     L) batch arrays with an activity mask.
   2. ``_run_packed_state`` scans the packed slots with the pure per-set
      kernels from ``controller`` (the same code the serial oracle runs),
      each step transitioning every set of a trace at once (one column per
@@ -58,7 +62,7 @@ from __future__ import annotations
 
 import os
 from functools import partial
-from typing import List, NamedTuple, Sequence, Tuple
+from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -131,25 +135,6 @@ def _bucket(n: int, minimum: int = 16) -> int:
     return 1 << (int(n) - 1).bit_length()
 
 
-def _dense_layout(set_idx: np.ndarray, n_sets: int, length: int,
-                  cols: Sequence[np.ndarray]
-                  ) -> Tuple[np.ndarray, List[np.ndarray]]:
-    """Scatter per-request columns into (n_sets, length) padded arrays,
-    preserving the original order within each set (stable sort)."""
-    order = np.argsort(set_idx, kind="stable")
-    ss = set_idx[order]
-    starts = np.searchsorted(ss, np.arange(n_sets))
-    slot = np.arange(len(ss)) - starts[ss]
-    active = np.zeros((n_sets, length), bool)
-    active[ss, slot] = True
-    out = []
-    for v in cols:
-        a = np.zeros((n_sets, length), v.dtype)
-        a[ss, slot] = v[order]
-        out.append(a)
-    return active, out
-
-
 _UNCOUNTED_POS = np.int32(-(1 << 30))
 
 
@@ -207,39 +192,46 @@ def set_tiling(cfg: MorpheusConfig
             engine_scan.set_tiling(cfg.amap.ext_sets, ext_rows, 5))
 
 
+def _scatter(arrs: Sequence[np.ndarray], i: int, flat: np.ndarray,
+             cols: Sequence[np.ndarray]) -> None:
+    """Write trace ``i``'s sorted columns of one tier into its batch arrays
+    (the last one the activity mask) at flat slots ``flat``."""
+    for arr, col in zip(arrs, cols):
+        arr[i].reshape(-1)[flat] = col
+    arrs[-1][i].reshape(-1)[flat] = True
+
+
 def _pack(cfg: MorpheusConfig,
           traces: Sequence[Tuple[np.ndarray, np.ndarray, np.ndarray, int]],
           pos0: Sequence[int] | None,
           count: Sequence[np.ndarray | None] | None) -> PackedTraces:
+    """One stable sort per trace on the global set, for both tiers at once
+    (conventional sets are numbered below extended ones, so the sorted
+    order is every conventional set's run, then every extended set's),
+    then each column scattered once, through a flat index, straight into
+    its batch array."""
     amap = cfg.amap
     total = max(amap.total_sets, 1)
     sc, se = amap.conv_sets, amap.ext_sets
     (tc, nc), (te, ne) = set_tiling(cfg)
     sc_pad, se_pad = tc * nc, te * ne
-    prepped = []
+    # the narrowest key that holds every set: 8 or 16 bits sort by radix
+    key_dt = np.min_scalar_type(total - 1)
+    sorted_ = []
     max_c = max_e = n_req = n_ext = 0
-    for i, (addrs, writes, levels, warmup) in enumerate(traces):
+    for addrs, _, _, _ in traces:
         addrs = np.asarray(addrs, np.uint32)
-        n_req += len(addrs)
-        writes = np.asarray(writes, bool)
-        levels = np.asarray(levels, np.int32)
-        gset = (addrs % np.uint32(total)).astype(np.int64)
-        tag = (addrs // np.uint32(total)).astype(np.uint32)
-        off = int(pos0[i]) if pos0 is not None else 0
-        pos = off + np.arange(len(addrs), dtype=np.int32)
-        if count is not None and count[i] is not None:
-            mask = np.asarray(count[i], bool)
-            assert mask.shape == addrs.shape, "count mask length mismatch"
-            pos = np.where(mask, pos, _UNCOUNTED_POS)
-        is_ext = gset >= sc if cfg.ext_enabled else np.zeros(len(addrs), bool)
-        n_ext += int(is_ext.sum())
+        key = (addrs % np.uint32(total)).astype(key_dt)
+        counts = np.bincount(key, minlength=total)
+        n_conv = int(counts[:sc].sum()) if se else len(addrs)
         if sc:
-            cnt = np.bincount(gset[~is_ext], minlength=sc)
-            max_c = max(max_c, int(cnt.max()) if cnt.size else 0)
+            max_c = max(max_c, int(counts[:sc].max()))
         if se:
-            cnt = np.bincount(gset[is_ext] - sc, minlength=se)
-            max_e = max(max_e, int(cnt.max()) if cnt.size else 0)
-        prepped.append((gset, tag, pos, is_ext, writes, levels, int(warmup)))
+            max_e = max(max_e, int(counts[sc:].max()))
+        n_req += len(addrs)
+        n_ext += len(addrs) - n_conv
+        sorted_.append((addrs, np.argsort(key, kind="stable"), counts,
+                        n_conv))
 
     lc = _bucket(max_c) if sc and max_c else 0
     le = _bucket(max_e) if se and max_e else 0
@@ -249,21 +241,36 @@ def _pack(cfg: MorpheusConfig,
     ext = [np.zeros((b, se_pad, le), dt) for dt in
            (np.uint32, bool, np.int32, np.int32, bool)]
     warmups = np.zeros((b,), np.int32)
-    for i, (gset, tag, pos, is_ext, writes, levels, warmup) in \
-            enumerate(prepped):
+    # a set's first flat slot in its tier's (sets, L) block
+    base = np.concatenate([np.arange(sc) * lc,
+                           np.arange(total - sc) * le])
+    ks = np.arange(max((len(o) for _, o, _, _ in sorted_), default=0))
+    for i, ((addrs, order, counts, n_conv), (_, writes, levels, warmup)) \
+            in enumerate(zip(sorted_, traces)):
         warmups[i] = warmup
+        # flat slot of the k-th sorted request: its set's base plus its
+        # rank within the set, k less the set's first sorted index
+        starts = np.cumsum(counts) - counts
+        flat = np.repeat(base - starts, counts)
+        flat += ks[:len(flat)]
+        tag = np.take(addrs, order) // np.uint32(total)
+        writes = np.take(np.asarray(writes, bool), order)
+        pos = order.astype(np.int32)
+        if pos0 is not None:
+            pos += int(pos0[i])
+        if count is not None and count[i] is not None:
+            mask = np.asarray(count[i], bool)
+            assert mask.shape == addrs.shape, "count mask length mismatch"
+            pos[~np.take(mask, order)] = _UNCOUNTED_POS
         if lc:
-            keep = ~is_ext
-            act, (t, w, p) = _dense_layout(
-                gset[keep], sc_pad, lc, (tag[keep], writes[keep], pos[keep]))
-            conv[0][i], conv[1][i], conv[2][i], conv[3][i] = t, w, p, act
+            c = slice(0, n_conv)
+            _scatter(conv, i, flat[c], (tag[c], writes[c], pos[c]))
         if le:
-            keep = is_ext
-            act, (t, w, l, p) = _dense_layout(
-                gset[keep] - sc, se_pad, le,
-                (tag[keep], writes[keep], levels[keep], pos[keep]))
-            (ext[0][i], ext[1][i], ext[2][i],
-             ext[3][i], ext[4][i]) = t, w, l, p, act
+            e = slice(n_conv, None)
+            _scatter(ext, i, flat[e],
+                     (tag[e], writes[e],
+                      np.take(np.asarray(levels, np.int32), order[e]),
+                      pos[e]))
     return (PackedTraces(conv[0], conv[1], conv[2], conv[3],
                          ext[0], ext[1], ext[2], ext[3], ext[4], warmups),
             n_req - n_ext, n_ext)

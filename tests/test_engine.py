@@ -305,3 +305,139 @@ def test_pack_pow2_padding_boundary(n, expect):
                             np.zeros(n, np.int32), 0)])
     assert pt.conv_tag.shape[2] == expect
     assert pt.conv_active[0, 0].sum() == n
+
+
+# ---------------------------------------------- pack against a plain layout
+
+_UNCOUNTED = -(1 << 30)
+
+
+def _reference_pack(cfg, traces, pos0=None, count=None):
+    """The layout ``engine.pack`` promises, one request at a time: append
+    each request to its (tier, set) list in trace order, then pad every
+    list to the busiest set's power-of-two length (at least 16) and each
+    tier's set axis to whole scan tiles.  Returns the PackedTraces and the
+    requests per tier."""
+    total = max(cfg.amap.total_sets, 1)
+    sc = cfg.amap.conv_sets
+    lists, n_tier = [], {"conv": 0, "ext": 0}
+    for i, (addrs, writes, levels, _) in enumerate(traces):
+        per = {}
+        for k in range(len(addrs)):
+            a = int(addrs[k])
+            g = a % total
+            tier, s = ("ext", g - sc) if cfg.ext_enabled and g >= sc \
+                else ("conv", g)
+            p = (pos0[i] if pos0 is not None else 0) + k
+            if count is not None and count[i] is not None \
+                    and not count[i][k]:
+                p = _UNCOUNTED
+            per.setdefault((tier, s), []).append(
+                (a // total, bool(writes[k]), int(levels[k]), p))
+            n_tier[tier] += 1
+        lists.append(per)
+
+    def length(tier):
+        most = max((len(v) for per in lists for (t, _), v in per.items()
+                    if t == tier), default=0)
+        return 0 if most == 0 else max(16, 1 << (most - 1).bit_length())
+
+    (tc, nc), (te, ne) = engine.set_tiling(cfg)
+    b, lc, le = len(traces), length("conv"), length("ext")
+    conv = [np.zeros((b, tc * nc, lc), dt)
+            for dt in (np.uint32, bool, np.int32, bool)]
+    ext = [np.zeros((b, te * ne, le), dt)
+           for dt in (np.uint32, bool, np.int32, np.int32, bool)]
+    for i, per in enumerate(lists):
+        for (tier, s), reqs in per.items():
+            for slot, (tag, write, level, p) in enumerate(reqs):
+                if tier == "conv":
+                    cols = zip(conv, (tag, write, p, True))
+                else:
+                    cols = zip(ext, (tag, write, level, p, True))
+                for arr, v in cols:
+                    arr[i, s, slot] = v
+    warmup = np.array([t[3] for t in traces], np.int32)
+    return engine.PackedTraces(*conv, *ext, warmup), n_tier
+
+
+def _layout_traces(total, lengths, seed):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(0, 64 * total, n).astype(np.uint32),
+             rng.random(n) < 0.3,
+             rng.integers(0, 3, n).astype(np.int32),
+             int(rng.integers(0, n + 1)))
+            for n in lengths]
+
+
+@pytest.mark.parametrize("system,n_cache,lengths,offsets,masked", [
+    pytest.param("Morpheus-ALL", 40, [3000, 3000], False, False,
+                 id="morpheus-all-1/8"),
+    pytest.param("IBL-4x-LLC", 0, [3000, 3000], False, False,
+                 id="ibl-4x-llc-no-ext"),
+    pytest.param("Morpheus-ALL@1", 51, [6000, 6000], False, False,
+                 id="fullscale-tiled"),
+    pytest.param("Morpheus-ALL", 40, [3000, 0, 1700, 7], False, False,
+                 id="ragged-lengths"),
+    pytest.param("Morpheus-ALL", 40, [2000, 2000], True, False,
+                 id="pos0"),
+    pytest.param("Morpheus-ALL", 40, [2000, 1500, 2000], False, True,
+                 id="count-mask"),
+    pytest.param("Morpheus-ALL@1", 51, [4000, 2500], True, True,
+                 id="fullscale-pos0-count"),
+])
+def test_pack_matches_plain_layout(system, n_cache, lengths, offsets,
+                                   masked):
+    """Every PackedTraces field equals the plain per-(tier, set) layout in
+    value, dtype and shape, and the tier counters count each tier's
+    requests."""
+    cfg = cs.build_config(cs.SYSTEMS[system], n_cache)
+    assert cfg.ext_enabled == (n_cache > 0)
+    traces = _layout_traces(cfg.amap.total_sets, lengths,
+                            _case_seed(system, lengths, offsets, masked))
+    rng = np.random.default_rng(len(lengths))
+    pos0 = [int(rng.integers(0, 1 << 20)) for _ in traces] \
+        if offsets else None
+    count = [None if i == 1 else rng.random(len(t[0])) < 0.6
+             for i, t in enumerate(traces)] if masked else None
+    want, n_tier = _reference_pack(cfg, traces, pos0, count)
+    obs.disable()
+    obs.enable(trace=False, metrics=True)
+    try:
+        got = engine.pack(cfg, traces, pos0=pos0, count=count)
+        reg = obs.metrics_registry()
+        per_tier = {dict(k)["tier"]: v
+                    for k, v in reg.get("tier_requests").values.items()}
+        tiers = {t: per_tier.get(t, 0) for t in ("conv", "ext")}
+        slots = reg.get("packed_slots").total()
+    finally:
+        obs.disable()
+    for f, w, g in zip(engine.PackedTraces._fields, want, got):
+        assert (g.dtype, g.shape) == (w.dtype, w.shape), f
+        np.testing.assert_array_equal(g, w, err_msg=f)
+    assert tiers == n_tier
+    assert slots == got.conv_tag.size + got.ext_tag.size
+
+
+def test_pack_key_wider_than_16_bits():
+    """More than 65 535 sets: the set key must not wrap at 16 bits, or set
+    65 536 + s would be packed into set s."""
+    amap = asep.make_map(conv_sets=66_000, num_cache_chips=2,
+                         sets_per_chip=300)
+    cfg = ctl.MorpheusConfig(amap=amap, conv_ways=4, ext_ways=4)
+    total = amap.total_sets
+    assert total > 65_535
+    rng = np.random.default_rng(3)
+    # sets s and 65 536 + s, both tiers, and random sets besides
+    low = rng.integers(0, total - 65_536, 500)
+    sets = np.concatenate([low, low + 65_536,
+                           rng.integers(0, total, 2000)])
+    addrs = (rng.integers(0, 8, len(sets)) * total + sets).astype(np.uint32)
+    trace = (addrs, rng.random(len(addrs)) < 0.3,
+             rng.integers(0, 3, len(addrs)).astype(np.int32), 0)
+    want, _ = _reference_pack(cfg, [trace])
+    got = engine.pack(cfg, [trace])
+    assert got.ext_active.any() and got.conv_active[0, 65_536:].any()
+    for f, w, g in zip(engine.PackedTraces._fields, want, got):
+        assert (g.dtype, g.shape) == (w.dtype, w.shape), f
+        np.testing.assert_array_equal(g, w, err_msg=f)
