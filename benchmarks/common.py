@@ -157,7 +157,7 @@ def mode_splits(systems: Sequence[str], apps: Sequence[str],
     backend computed it first.  Splits come from an argmin over
     float-derived exec times, which can differ between backends by
     accumulation order on near-tie grid cells — measured agreement is
-    45/45 on the Table-3 sweep (tools/bench_engine.py), so we accept
+    45/45 on the Table-3 sweep, so we accept
     that tie-break caveat rather than fragment the cache per backend."""
     from repro.core import cache_sim as cs
     from repro.core import policy

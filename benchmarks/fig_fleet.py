@@ -13,8 +13,6 @@ Three claims about ``repro.runtime.fleet``:
      be independent of how many rows were batched around it (replicas
      are independent; batching must not perturb the physics).  Engine
      dispatches per epoch stay O(config groups), not O(replicas).
-     Wall-clock throughput is ``tools/bench_fleet.py``'s job, not this
-     figure's.
   3. **Advisor** — warm-starting fresh replicas from the shared
      ``SplitAdvisor`` puts them AT the fleet's converged split at epoch
      0, cutting mean governor convergence time vs. the cold ablation.

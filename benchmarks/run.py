@@ -18,7 +18,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma-separated module keys (fig1,fig2,fig5,fig11,"
-                         "fig12,fig13,tab3,bw,overheads,roofline,online,"
+                         "fig12,fig13,tab3,bw,overheads,online,"
                          "serving,qos,overload,fleet,autotune,"
                          "char_online)")
     ap.add_argument("--profile", default=None, choices=("quick", "std", "full"))
@@ -39,16 +39,14 @@ def main() -> None:
                    fig5_latency, fig11_characterization, fig12_endtoend,
                    fig13_predictor, fig_autotune,
                    fig_characterization_online, fig_fleet, fig_online,
-                   fig_overload, fig_qos, fig_serving, roofline_table,
-                   tab3_mode_split, tab_overheads)
+                   fig_overload, fig_qos, fig_serving, tab3_mode_split,
+                   tab_overheads)
 
     modules = {
         "fig5": ("Fig. 5 latency timelines", fig5_latency.run),
         "fig11": ("Fig. 11 extended-LLC characterization",
                   fig11_characterization.run),
         "overheads": ("§7.5 overheads", tab_overheads.run),
-        "roofline": ("§Roofline table (dry-run aggregation)",
-                     roofline_table.run),
         "fig1": ("Fig. 1 core scaling", fig1_core_scaling.run),
         "fig2": ("Fig. 2 LLC sizes", fig2_llc_size.run),
         "tab3": ("Table 3 mode split", tab3_mode_split.run),

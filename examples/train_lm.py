@@ -1,10 +1,8 @@
 """End-to-end training driver: data pipeline -> train loop -> checkpoint
 -> restart, with the fault-tolerance supervisor.
 
-Defaults are CPU-friendly (a reduced config, 60 steps).  On a real pod,
-pass ``--arch <assigned-arch> --full --steps 300`` and a mesh is built via
-``repro.launch.mesh.make_production_mesh()``; the same code path lowers
-under pjit with the sharding rules in ``repro.distributed.sharding``.
+Defaults are CPU-friendly (a reduced config, 60 steps); ``--full`` trains
+the assigned config at its published widths.
 
   PYTHONPATH=src python examples/train_lm.py
   PYTHONPATH=src python examples/train_lm.py --arch mamba2-780m --steps 40

@@ -4,7 +4,6 @@ from __future__ import annotations
 from typing import Dict
 
 from .base import ArchConfig, LayerSpec, GLOBAL, LOCAL, MAMBA
-from .shapes import SHAPES, ShapeConfig
 
 from .seamless_m4t_medium import CONFIG as _seamless
 from .h2o_danube_1_8b import CONFIG as _danube
@@ -29,18 +28,5 @@ def get(name: str) -> ArchConfig:
     return ALL_ARCHS[name]
 
 
-def cells(include_skipped: bool = False):
-    """All assigned (arch x shape) dry-run cells.  ``long_500k`` is skipped
-    for pure full-attention archs (see DESIGN.md §long_500k skip notes)."""
-    out = []
-    for aname, cfg in ALL_ARCHS.items():
-        for sname, shape in SHAPES.items():
-            skipped = (sname == "long_500k" and not cfg.supports_long_context)
-            if skipped and not include_skipped:
-                continue
-            out.append((aname, sname, skipped))
-    return out
-
-
 __all__ = ["ArchConfig", "LayerSpec", "GLOBAL", "LOCAL", "MAMBA",
-           "SHAPES", "ShapeConfig", "ALL_ARCHS", "get", "cells"]
+           "ALL_ARCHS", "get"]

@@ -67,23 +67,13 @@ class ArchConfig:
     encoder_layers: int = 0
     # numerics / embeddings
     tie_embeddings: bool = True
-    # NOTE on dtype (§Perf iteration 3, refuted-on-substrate): bf16
-    # params/activations are the TPU production default and would halve the
-    # HBM-byte and collective roofline terms.  The dry-run however compiles
-    # on the CPU backend, whose float-normalization pass promotes every
-    # bf16 compute op to f32 (verified: 1/82 dots stayed bf16), so the
-    # measured terms for a bf16 config are the SAME graph plus convert
-    # traffic — strictly worse numbers for a strictly better program.  We
-    # therefore measure in f32 (matching what the CPU backend actually
-    # lowers) and record the bf16 projection (bytes/2 on activation and
-    # gradient traffic) in EXPERIMENTS.md instead of silently mixing the
-    # two.  Archs whose public checkpoints are bf16 (gemma3, jamba) keep it.
+    # f32 by default; archs whose public checkpoints are bf16 (gemma3,
+    # jamba) keep it.
     param_dtype: str = "float32"
     # remat policy for the scanned block ("full" | "dots"), see §Perf
     remat_policy: str = "full"
     # assignment metadata
     morpheus_enabled: bool = True
-    supports_long_context: bool = False  # run long_500k? (sub-quadratic attn)
     source: str = ""
     notes: str = ""
 

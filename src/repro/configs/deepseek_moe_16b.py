@@ -24,6 +24,5 @@ CONFIG = ArchConfig(
     act="silu",
     rope_theta=10_000.0,
     tie_embeddings=False,
-    supports_long_context=False,   # full attention -> skip long_500k
     source="arXiv:2401.06066; hf",
 )

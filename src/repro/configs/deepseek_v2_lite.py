@@ -31,7 +31,6 @@ CONFIG = ArchConfig(
     act="silu",
     rope_theta=10_000.0,
     tie_embeddings=False,
-    supports_long_context=False,   # full attention -> skip long_500k
     source="arXiv:2405.04434; hf",
     notes="MLA compressed-KV cache pages are what Morpheus caches here",
 )

@@ -20,6 +20,5 @@ CONFIG = ArchConfig(
     act="gelu",
     rope_theta=10_000.0,
     tie_embeddings=True,
-    supports_long_context=True,     # half the layers are window-bounded
     source="arXiv:2408.00118; hf",
 )

@@ -23,6 +23,5 @@ CONFIG = ArchConfig(
     rope_theta=1_000_000.0,
     tie_embeddings=True,
     param_dtype="bfloat16",
-    supports_long_context=True,     # 5:1 local dominates; global KV sharded
     source="hf:google/gemma-3-1b-pt; unverified",
 )

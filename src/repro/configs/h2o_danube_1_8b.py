@@ -18,6 +18,5 @@ CONFIG = ArchConfig(
     act="silu",
     rope_theta=10_000.0,
     tie_embeddings=False,
-    supports_long_context=True,    # SWA -> KV bounded by window
     source="arXiv:2401.16818; hf",
 )

@@ -42,6 +42,5 @@ CONFIG = ArchConfig(
     ssm_groups=8,
     tie_embeddings=False,
     param_dtype="bfloat16",
-    supports_long_context=True,   # SSM-dominated -> run long_500k
     source="arXiv:2403.19887; hf",
 )

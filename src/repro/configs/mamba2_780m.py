@@ -26,6 +26,5 @@ CONFIG = ArchConfig(
     # (-19% HLO FLOPs, -2% HBM bytes vs full recompute at this scale)
     remat_policy="dots",
     morpheus_enabled=False,
-    supports_long_context=True,    # O(1) state -> run long_500k
     source="arXiv:2405.21060; unverified",
 )

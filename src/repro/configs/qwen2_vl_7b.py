@@ -22,7 +22,6 @@ CONFIG = ArchConfig(
     act="silu",
     rope_theta=1_000_000.0,
     tie_embeddings=False,
-    supports_long_context=False,   # pure full attention -> skip long_500k
     source="arXiv:2409.12191; hf",
     notes="vision patch frontend stubbed to precomputed patch embeddings",
 )

@@ -18,6 +18,5 @@ CONFIG = ArchConfig(
     act="silu",
     rope_theta=1_000_000.0,
     tie_embeddings=True,
-    supports_long_context=False,    # pure full attention -> skip long_500k
     source="hf:Qwen/Qwen3-8B; hf",
 )

@@ -20,7 +20,6 @@ CONFIG = ArchConfig(
     act="gelu",
     rope_theta=10_000.0,
     tie_embeddings=True,
-    supports_long_context=False,   # full attention -> skip long_500k
     source="arXiv:2308.11596; hf",
     notes="enc-dec; audio frontend stubbed to precomputed frame embeddings",
 )

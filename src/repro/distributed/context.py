@@ -1,10 +1,10 @@
 """Ambient distribution context.
 
-Launchers (dryrun / train / serve) install the active mesh here; layers
-whose optimal implementation is an explicit shard_map (today: the MoE
-dispatch, §Perf iteration moe-1) pick it up.  When no mesh is installed
-(unit tests, single-host examples) layers use their pure-jnp path — the
-two paths are numerically identical (tests/test_moe_shardmap.py).
+A caller installs the active mesh here (``use_mesh``); layers whose
+optimal implementation is an explicit shard_map (today: the MoE dispatch)
+pick it up.  When no mesh is installed (the launchers, unit tests,
+single-host examples) layers use their pure-jnp path — the two paths are
+numerically identical (tests/test_perf_rewrites.py).
 """
 from __future__ import annotations
 

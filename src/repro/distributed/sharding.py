@@ -1,7 +1,6 @@
-"""Sharding rules: parameter, optimizer-state, activation and KV-cache
-PartitionSpecs for the production meshes.
+"""Sharding rules: parameter PartitionSpecs, and the cache-sim fleet axis.
 
-Conventions (see DESIGN.md §5):
+Conventions:
   * ``pod``   — pure data parallelism across pods (gradient all-reduce)
   * ``data``  — data parallelism / context parallelism for long decode
   * ``model`` — tensor parallelism: heads, d_ff, experts, vocab, d_inner
@@ -9,17 +8,14 @@ Conventions (see DESIGN.md §5):
 Parameters are matched by their pytree path leaf-name; any unmatched array
 is replicated.  Divisibility is always checked — a dim that does not tile
 over the axis falls back to replication rather than producing a compile
-error (recorded by ``explain()`` for the dry-run report).
+error.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..configs.base import ArchConfig
 
 # leaf-name -> (dim -> logical axis) ; dims not listed are replicated
 _PARAM_RULES: Dict[str, Dict[int, str]] = {
@@ -57,10 +53,6 @@ def _axis_size(mesh: Mesh, name: str) -> int:
 
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.shape)
-
-
-def batch_axis_size(mesh: Mesh) -> int:
-    return int(np.prod([mesh.shape[a] for a in batch_axes(mesh)]))
 
 
 def _spec_for(path, leaf, mesh: Mesh) -> P:
@@ -109,72 +101,6 @@ def param_shardings(params_shape: Any, mesh: Mesh) -> Any:
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def opt_state_specs(params_shape: Any, mesh: Mesh, *, zero1: bool = True) -> Any:
-    """Adam moment sharding.  With ``zero1`` the largest replicated dim of
-    each moment is additionally sharded over ``data`` (ZeRO-1-style optimizer
-    state partitioning) when divisible."""
-    specs = param_specs(params_shape, mesh)
-
-    def zero_one(path, leaf, spec: P):
-        if not zero1:
-            return spec
-        parts = list(spec) + [None] * (len(leaf.shape) - len(spec))
-        dsize = _axis_size(mesh, "data")
-        for d in np.argsort([-s for s in leaf.shape]):
-            d = int(d)
-            if parts[d] is None and leaf.shape[d] % dsize == 0 and \
-                    leaf.shape[d] >= 4 * dsize:
-                parts[d] = "data"
-                break
-        return P(*parts)
-
-    return jax.tree_util.tree_map_with_path(
-        lambda p, leaf, s: zero_one(p, leaf, s), params_shape, specs)
-
-
-# ------------------------------------------------------------- activations
-
-def batch_spec(mesh: Mesh, global_batch: int, ndim: int = 2) -> P:
-    """Shard dim0 (batch) over pod+data when divisible, else replicate."""
-    axes = batch_axes(mesh)
-    if axes and global_batch % batch_axis_size(mesh) == 0:
-        return P(axes, *([None] * (ndim - 1)))
-    return P(*([None] * ndim))
-
-
-def kv_cache_specs(cfg: ArchConfig, mesh: Mesh, global_batch: int) -> Dict[str, P]:
-    """Sharding for decode KV caches (per layer leaf name).
-
-    Heads shard over ``model`` when divisible; otherwise the *sequence* dim
-    shards over ``model`` (flash-decoding-style context parallelism), which
-    also covers the batch=1 long-context case.  Batch shards over pod+data
-    when divisible (else sequence takes ``data`` too)."""
-    m = _axis_size(mesh, "model")
-    baxes = batch_axes(mesh)
-    batch_ok = global_batch % batch_axis_size(mesh) == 0 and len(baxes) > 0
-    b_ax = baxes if batch_ok else None
-    heads_ok = cfg.num_kv_heads % m == 0 and not cfg.mla
-    if heads_ok:
-        seq_ax = None if batch_ok else "data"
-        head_ax = "model"
-    else:
-        seq_ax = ("model",) if batch_ok else ("data", "model")
-        head_ax = None
-    out = {
-        "k": P(b_ax, seq_ax, head_ax, None),
-        "v": P(b_ax, seq_ax, head_ax, None),
-        "pos": P(None),
-        # MLA latent caches: no head dim; shard sequence
-        "c_kv": P(b_ax, seq_ax if seq_ax else ("model",), None),
-        "k_rope": P(b_ax, seq_ax if seq_ax else ("model",), None, None),
-        # mamba caches
-        "conv": P(b_ax, None, "model"),
-        "ssm": P(b_ax, "model" if cfg.ssm_heads % max(m, 1) == 0 else None,
-                 None, None),
-    }
-    return out
-
-
 # ------------------------------------------------------------ fleet axis
 #
 # The cache-sim fleet (runtime/fleet.py) stacks N replicas' EngineState
@@ -208,34 +134,3 @@ def fleet_padding(n_rows: int, mesh: Optional[Mesh] = None, *,
         target = ((target + ax - 1) // ax) * ax
     return target - n_rows
 
-
-def cache_shardings(cfg: ArchConfig, caches_shape: Any, mesh: Mesh,
-                    global_batch: int) -> Any:
-    table = kv_cache_specs(cfg, mesh, global_batch)
-
-    def spec(path, leaf):
-        name = None
-        for k in reversed(path):
-            if isinstance(k, jax.tree_util.DictKey):
-                name = str(k.key)
-                break
-        if name in table:
-            s = table[name]
-            parts = list(s)
-            # stacked block caches get a leading blocks dim -> prepend None
-            extra = len(leaf.shape) - len(parts)
-            parts = [None] * extra + parts
-            # drop specs for dims that don't divide
-            for i, ax in enumerate(parts):
-                if ax is None:
-                    continue
-                axes = (ax,) if isinstance(ax, str) else tuple(ax)
-                size = int(np.prod([_axis_size(mesh, a) for a in axes]))
-                if leaf.shape[i] % size != 0:
-                    parts[i] = None
-            return NamedSharding(mesh, P(*parts))
-        if name == "enc_out":
-            return NamedSharding(mesh, batch_spec(mesh, global_batch, 3))
-        return NamedSharding(mesh, P())
-
-    return jax.tree_util.tree_map_with_path(spec, caches_shape)

@@ -1,28 +1,11 @@
-"""Production mesh construction.
+"""Mesh construction for the cache-sim fleet runtime.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so
-importing this module never touches jax device state — tests and benches
-keep seeing 1 CPU device; only the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
-init.
+Only a function, never a module-level mesh: importing this module does
+not touch jax device state.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_dev_mesh(model_parallel: int = 1):
-    """Small mesh over whatever devices exist (tests / examples)."""
-    n = len(jax.devices())
-    assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
 
 
 def make_fleet_mesh(max_devices: int | None = None):

@@ -1,9 +1,7 @@
 """Serving launcher — the paper's technique as a deployed feature.
 
-Host mode runs the continuous-batching engine with the two-tier Morpheus
-page pool on a reduced config (CPU-friendly); pod mode lowers the sharded
-one-token `serve_step` for the production mesh (decode shapes), which is
-the same artifact the multi-pod dry-run validates.
+Runs the continuous-batching engine with the two-tier Morpheus page pool
+on a reduced config (CPU-friendly).
 
 ``--split`` chooses the page pool's mode split: an integer pins the
 cache-chip count; ``auto`` attaches the adaptive runtime governor
@@ -39,18 +37,14 @@ lowest-priority tenants when the joint SLO set is unattainable
       --split auto --workload tenantA,tenantB --arrival onoff:64,0.5,0.5
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
       --split auto --workload tenantA,tenantB --slo-ms 2.5
-  PYTHONPATH=src python -m repro.launch.serve --arch deepseek-v2-lite-16b \
-      --mesh multipod --shape decode_32k --dry-run
 """
 import argparse
-import json
-import os
 import time
 
 
 def main(argv=None):
-    """Parse ``argv`` (default: the command line) and serve; host mode
-    returns the serving ``Engine`` for in-process callers."""
+    """Parse ``argv`` (default: the command line) and serve; returns the
+    serving ``Engine`` for in-process callers."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
     ap.add_argument("--batch", type=int, default=4)
@@ -91,10 +85,6 @@ def main(argv=None):
                          "SLO set is unattainable, deferred work aged "
                          "back in (docs/qos.md), overload pressure fed "
                          "to the --split auto governor")
-    ap.add_argument("--mesh", choices=("host", "pod", "multipod"),
-                    default="host")
-    ap.add_argument("--shape", default="decode_32k")
-    ap.add_argument("--dry-run", action="store_true")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="enable observability and write a Chrome/"
                          "Perfetto trace-event JSON here on exit "
@@ -119,25 +109,6 @@ def main(argv=None):
     if args.trace_out or args.metrics_out or args.inspect_out:
         obs.enable(trace=args.trace_out is not None,
                    inspect=args.inspect_out is not None)
-
-    if args.mesh != "host":
-        if "xla_force_host_platform_device_count" not in \
-                os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + " --xla_force_host_platform_device_count=512")
-        from repro.launch import dryrun as D
-        rep = D.lower_cell(args.arch, args.shape,
-                           multi_pod=args.mesh == "multipod")
-        print(json.dumps({k: rep[k] for k in
-                          ("arch", "shape", "mesh", "chips", "dominant",
-                           "t_compute_s", "t_memory_s", "t_collective_s")},
-                         indent=1))
-        if not args.dry_run:
-            print("NOTE: production-mesh serving requires real hosts; the "
-                  "sharded serve_step compiled successfully.")
-        _save_obs(args)
-        return None
 
     import jax
 

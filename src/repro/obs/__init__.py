@@ -16,9 +16,9 @@ deterministic artifact byte (tests/test_obs.py pins bit-identity):
     additionally emitted as trace instant events when tracing is on).
 
 Activation: ``obs.enable()`` (both), ``obs.enable(trace=False)``
-(counters only — what the bench tools use, cheap enough to keep on), or
-environment ``REPRO_OBS=1`` at import.  ``obs.disable()`` drops both;
-the tracer/registry objects stay readable by whoever holds them.
+(counters only — cheap enough to keep on), or environment ``REPRO_OBS=1``
+at import.  ``obs.disable()`` drops both; the tracer/registry objects
+stay readable by whoever holds them.
 
 This package imports nothing from the rest of ``repro`` (and jax only
 lazily, inside the compile hook and ``enable(trace=True)``), so every
@@ -36,7 +36,7 @@ from .decision import (ADMISSION_KINDS, TRIGGERS,  # noqa: F401
                        AdmissionEvent, DecisionEvent)
 from .inspect import Inspector, Snapshot  # noqa: F401
 from .metrics import (Registry, admission_counters,  # noqa: F401
-                      bench_counters, count, observe, set_gauge)
+                      count, observe, set_gauge)
 from .trace import NULL_SPAN, Span, Tracer  # noqa: F401
 
 _TRACER: Optional[Tracer] = None

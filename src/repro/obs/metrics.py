@@ -8,7 +8,7 @@ One ``Registry`` holds every metric of a run; exposition is dual:
     dispatches/s) are the scraper's ``rate()`` over them, never computed
     here from wall clock (exports stay timestamp-free);
   * ``snapshot()`` / ``save()`` — a JSON document for offline tooling
-    (``tools/obs_report.py``, the bench counters in ``BENCH_*.json``).
+    (``tools/obs_report.py``).
 
 Metric names are short canonical slugs ("engine_dispatches"); the
 Prometheus renderer prefixes ``morpheus_`` and suffixes counters with
@@ -270,39 +270,11 @@ def _install_compile_hook() -> None:
     _HOOK_INSTALLED = True
 
 
-# --------------------------------------------------------- bench counters
-
-#: canonical counters the bench tools embed in ``BENCH_*.json`` v2
-BENCH_COUNTER_KEYS = {
-    "dispatches": "engine_dispatches",
-    "compiles": "jax_compiles",
-    "device_get_bytes": "device_get_bytes",
-    "flush_writebacks": "flush_writebacks",
-    "epochs": "epochs",
-    "snapshots": "state_snapshots",
-}
-
-
-def bench_counters(reg: Optional[Registry] = None) -> Dict[str, float]:
-    """Flat {key: total} over the canonical bench counters (0 for
-    counters the run never touched) — ``tools/bench_schema.write_bench``
-    embeds this verbatim."""
-    reg = reg if reg is not None else _ACTIVE
-    out: Dict[str, float] = {}
-    for key, name in BENCH_COUNTER_KEYS.items():
-        m = reg.get(name) if reg is not None else None
-        v = m.total() if m is not None else 0
-        out[key] = int(v) if float(v).is_integer() else float(v)
-    return out
-
-
 def admission_counters(reg: Optional[Registry] = None) -> Dict[str, int]:
     """Flat {kind: requests} over the admission-control taxonomy
     (``repro.obs.decision.ADMISSION_KINDS``), read from the
     ``admission_requests`` counter's per-kind label sets; 0 for kinds the
-    run never emitted.  Deliberately NOT part of ``BENCH_COUNTER_KEYS``:
-    the committed ``BENCH_*.json`` baselines are schema-validated against
-    that exact key set, so the QoS view is additive on the side."""
+    run never emitted."""
     reg = reg if reg is not None else _ACTIVE
     kinds = ("admit", "defer", "shed", "resume")
     out = {k: 0 for k in kinds}

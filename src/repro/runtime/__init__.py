@@ -13,7 +13,7 @@ be in cache mode for the work arriving *right now*?
     epsilon-greedy search over the offline policy's candidate splits,
     with hysteresis and phase-shift detection.
   * ``telemetry`` — per-epoch ring-buffer log with JSON/CSV export,
-    consumed by ``tools/bench_runtime.py`` and ``benchmarks/fig_online``.
+    consumed by ``benchmarks/fig_online``.
   * ``fleet``     — N replicas per dispatch: same-config replicas batch
     into one (optionally shard_map-sharded) engine step, with a shared
     split-advisor for cross-replica warm starts (docs/fleet.md).

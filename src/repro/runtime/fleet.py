@@ -31,9 +31,7 @@ the best split and phase/context tables any replica converged to
 (snapshots via ``Governor.export_state``); a new replica serving a
 known mix warm-starts there instead of re-climbing the candidate
 ladder.  ``benchmarks/fig_fleet.py`` ablates the advisor on/off and
-reports aggregate IPC + convergence time vs. replica count;
-``tools/bench_fleet.py`` measures warm fleet-step throughput vs. the
-serial loop.
+reports aggregate IPC + convergence time vs. replica count.
 """
 from __future__ import annotations
 
@@ -429,8 +427,7 @@ def run_serial(specs, *, backend: Optional[str] = None
                ) -> List[OnlineResult]:
     """The Python-loop baseline: every replica advanced one at a time,
     one dispatch per replica per epoch — exactly ``simulate_online``'s
-    loop.  The tests' bit-identity reference and the speedup denominator
-    in ``tools/bench_fleet.py``."""
+    loop.  The tests' bit-identity reference."""
     backend = engine.resolve_backend(backend)
     reps = [s if isinstance(s, OnlineReplica) else s.build() for s in specs]
     for rep in reps:

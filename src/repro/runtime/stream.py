@@ -82,8 +82,7 @@ class EpochStream:
     ``ring`` keeps up to that many upcoming epochs pre-packed and
     device-resident: the per-epoch host packing happens ahead of the
     dispatch loop and the stream never blocks on a device readback to
-    learn its own position (the position is mirrored on host), which is
-    the per-epoch overhead ``tools/bench_runtime.py`` measures.
+    learn its own position (the position is mirrored on host).
     """
 
     def __init__(self, cfg: MorpheusConfig, addrs, writes=None, levels=None,

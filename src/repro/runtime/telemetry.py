@@ -4,7 +4,7 @@ One ``EpochRecord`` is appended per epoch by the streaming drivers
 (``runtime.governor.simulate_online``, the serving governor hook).  The
 log is a fixed-capacity ring buffer — a long-running server keeps the
 most recent ``capacity`` epochs — with loss-free export for the benchmark
-harness (``benchmarks/fig_online``) and ``tools/bench_runtime.py``.
+harness (``benchmarks/fig_online``).
 
 Schema (one row per epoch, documented in docs/runtime.md):
 

@@ -1,6 +1,7 @@
 """The Table-1 GPU at its published capacity (``Morpheus-ALL@1``): the scale
 as a property of the modelled system, the set-tiled Pallas scan, and the
-nine 1/8-scale systems left as they were.
+nine 1/8-scale systems left as they were, each system's tier shapes on the
+jnp engine, the Pallas engine and the serial oracle.
 
 At ``sim_scale`` 1 the extended tier of 51 cache-mode SMs has 4 182 sets,
 more than one VMEM tile of the scan holds, so the scan runs over several
@@ -10,6 +11,7 @@ import json
 import sys
 from pathlib import Path
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -163,12 +165,52 @@ INTS = ("conv_hits", "conv_misses", "ext_hits", "ext_false_pos",
         "bloom_swaps")
 
 
+# the same kmeans point on the full-scale system, as the program gave it
+# when all three paths below first agreed
+PINNED_FULL = ((45, 1479, 122, 2, 4352, 4354, 5833, 0, 0), 3586854.0,
+               131544.0)
+
+# the jnp and the Pallas engine (one ``run_batch`` each), and the serial
+# oracle ``controller.simulate_jit`` on the trace ``_prepare`` makes
+PATHS = ("jnp", "pallas", "serial")
+
+
+def _kmeans_stats(path):
+    points = [cs.RunPoint("kmeans", n, 24, 44, 12_000, 7,
+                          "" if path == "serial" else path)
+              for n in cs.SYSTEMS]
+    if path != "serial":
+        return dict(zip(cs.SYSTEMS, (r.stats for r in cs.run_batch(points))))
+    out = {}
+    for name, pt in zip(cs.SYSTEMS, points):
+        cfg, (addrs, writes, levels, warmup), *_ = cs._prepare(pt)
+        out[name] = ctl.simulate_jit(cfg, jnp.asarray(addrs),
+                                     jnp.asarray(writes),
+                                     jnp.asarray(levels), warmup)
+    return out
+
+
 @pytest.fixture(scope="module")
-def eighth_scale_results():
-    names = list(PINNED)
-    res = cs.run_batch([cs.RunPoint("kmeans", n, 24, 44, 12_000, 7, "jnp")
-                        for n in names])
-    return dict(zip(names, res))
+def kmeans_stats():
+    """The kmeans point's Stats on every system by one path, each path
+    computed once for the module."""
+    done = {}
+
+    def get(path):
+        if path not in done:
+            done[path] = _kmeans_stats(path)
+        return done[path]
+    return get
+
+
+def _assert_pinned(stats, pinned, path):
+    ints, latency, energy = pinned
+    assert tuple(int(np.asarray(getattr(stats, f))) for f in INTS) == ints
+    # the serial oracle sums the float Stats request by request, the
+    # engines set by set: they agree within the benchmark's float limit
+    rtol = LIMITS["float_rel_err"] if path == "serial" else 1e-6
+    np.testing.assert_allclose(float(stats.latency_ns), latency, rtol=rtol)
+    np.testing.assert_allclose(float(stats.energy_nJ), energy, rtol=rtol)
 
 
 def test_only_the_full_scale_system_is_new():
@@ -176,14 +218,22 @@ def test_only_the_full_scale_system_is_new():
     assert cs.SYSTEMS["Morpheus-ALL@1"].sim_scale == 1
 
 
-@pytest.mark.parametrize("name", list(PINNED))
-def test_eighth_scale_systems_keep_scale_and_stats(eighth_scale_results,
-                                                   name):
+# the jnp engine's cases are named by the system alone
+@pytest.mark.parametrize("name,path", [
+    pytest.param(n, p, id=n if p == "jnp" else f"{n}-{p}")
+    for p in PATHS for n in PINNED])
+def test_eighth_scale_systems_keep_scale_and_stats(kmeans_stats, name,
+                                                   path):
     assert cs.SYSTEMS[name].sim_scale == 8
-    stats = eighth_scale_results[name].stats
-    ints, latency, energy = PINNED[name]
-    assert tuple(int(np.asarray(getattr(stats, f))) for f in INTS) == ints
-    np.testing.assert_allclose(float(stats.latency_ns), latency, rtol=1e-6)
-    np.testing.assert_allclose(float(stats.energy_nJ), energy, rtol=1e-6)
+    _assert_pinned(kmeans_stats(path)[name], PINNED[name], path)
     cfg = cs.build_config(cs.SYSTEMS[name], 44)
     assert all(tiles == 1 for _, tiles in engine.set_tiling(cfg))
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_full_scale_system_keeps_stats(kmeans_stats, path):
+    """``Morpheus-ALL@1`` at the 1/8-scale systems' kmeans point: its
+    extended tier spans several set tiles of the scan."""
+    _assert_pinned(kmeans_stats(path)["Morpheus-ALL@1"], PINNED_FULL, path)
+    cfg = cs.build_config(cs.SYSTEMS["Morpheus-ALL@1"], 44)
+    assert engine.set_tiling(cfg)[1][1] > 1

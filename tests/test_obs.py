@@ -18,8 +18,7 @@ Headline properties (acceptance):
     are built with tracing off; ``run_batch``'s five phase spans cover
     the call, and its results are bit-identical with obs on or off;
   * ``TelemetryLog`` exports oldest -> newest even after the ring wraps;
-  * bench documents round-trip schema v2 (optional ``counters``) while
-    v1 files stay valid; ``tools/obs_report.py`` renders a bundle.
+  * ``tools/obs_report.py`` renders a bundle.
 """
 import json
 import subprocess
@@ -42,10 +41,6 @@ from repro.runtime.telemetry import FIELDS, EpochRecord, TelemetryLog
 from repro.workloads.serving import SLOBudgeter
 
 ROOT = Path(__file__).resolve().parents[1]
-sys.path.insert(0, str(ROOT / "tools"))
-
-import bench_compare  # noqa: E402
-import bench_schema as bs  # noqa: E402
 
 _pallas_ok, _pallas_why = engine.backend_status("pallas")
 
@@ -56,6 +51,13 @@ def _obs_off():
     obs.disable()
     yield
     obs.disable()
+
+
+def _total(name):
+    """A counter's total over all its label sets in the active registry
+    (0 for a counter the run never touched)."""
+    m = obs.metrics_registry().get(name)
+    return m.total() if m is not None else 0
 
 
 def _record(epoch, **kw):
@@ -333,9 +335,8 @@ def test_module_helpers_route_to_active_registry():
     obs.count("engine_dispatches", 2, path="epoch")
     obs.set_gauge("slo_attainment", 0.5)
     obs.observe("span_ns", 42.0)
-    c = obs.bench_counters()
-    assert c["dispatches"] == 2
-    assert c["compiles"] >= 0 and c["epochs"] == 0
+    assert _total("engine_dispatches") == 2
+    assert _total("jax_compiles") >= 0 and _total("epochs") == 0
     obs.disable()
     # helpers silently drop once deactivated
     obs.count("engine_dispatches", 99)
@@ -349,10 +350,10 @@ def test_compile_hook_counts_real_xla_compiles():
     f = jax.jit(lambda x: x * 3 + 1)
     x = jnp.arange(7)
     f(x).block_until_ready()
-    n1 = obs.bench_counters()["compiles"]
+    n1 = _total("jax_compiles")
     assert n1 >= 1, "compile hook missed a fresh XLA build"
     f(x).block_until_ready()    # cached: no new executable
-    assert obs.bench_counters()["compiles"] == n1
+    assert _total("jax_compiles") == n1
 
 
 # -------------------------------------------------------------- telemetry
@@ -526,10 +527,10 @@ def test_online_run_emits_trace_instants_and_counters():
     assert len(instants) == len(r.decisions)
     names = {e["name"] for e in t.events}
     assert "governor.decide" in names
-    c = obs.bench_counters()
-    assert c["dispatches"] == len(r.records) == c["epochs"]
-    assert c["device_get_bytes"] > 0
-    assert c["flush_writebacks"] == \
+    assert _total("engine_dispatches") == len(r.records) == \
+        _total("epochs")
+    assert _total("device_get_bytes") > 0
+    assert _total("flush_writebacks") == \
         sum(rec.flush_writebacks for rec in r.records)
 
 
@@ -580,38 +581,6 @@ def test_slo_budgeter_tracks_attainment():
     assert b.rounds_observed == 2
 
 
-# --------------------------------------------------------- bench schema v2
-
-def test_bench_schema_v2_counters_roundtrip(tmp_path):
-    p = bs.write_bench("unit", "quick", {"step warm": 1.0},
-                       counters={"dispatches": 12, "epochs": 4},
-                       path=tmp_path / "b.json")
-    doc = bs.load_bench(p)
-    assert doc["schema"] == 2
-    assert doc["counters"] == {"dispatches": 12, "epochs": 4}
-    assert bench_compare.validate([p]) == 0
-
-
-def test_bench_schema_v1_still_valid(tmp_path):
-    p = bs.write_bench("unit", "quick", {"step warm": 1.0},
-                       path=tmp_path / "b.json")
-    doc = json.loads(p.read_text())
-    doc["schema"] = 1                    # what a committed v1 file says
-    doc.pop("counters", None)
-    p.write_text(json.dumps(doc))
-    assert bs.load_bench(p)["schema"] == 1
-    bad = dict(doc, schema=1, counters={"dispatches": 1})
-    with pytest.raises(AssertionError):
-        bs.validate(bad)                 # counters require schema >= 2
-
-
-def test_bench_path_env_override(tmp_path, monkeypatch):
-    target = tmp_path / "redirect.json"
-    monkeypatch.setenv("REPRO_BENCH_PATH", str(target))
-    p = bs.write_bench("unit", "quick", {"step warm": 1.0})
-    assert p == target and target.exists()
-
-
 # ----------------------------------------- cache microscope (ISSUE 9)
 
 def _stats_ints(stats):
@@ -646,9 +615,9 @@ def test_snapshot_counter_and_decode_sanity():
     obs.enable(trace=False, metrics=True, inspect=True)
     r = _online()
     snaps = obs.inspector().snapshots
-    c = obs.bench_counters()
+    n_snaps = _total("state_snapshots")
     obs.disable()
-    assert len(snaps) == len(r.records) == c["snapshots"]
+    assert len(snaps) == len(r.records) == n_snaps
     assert [s.epoch for s in snaps] == sorted(s.epoch for s in snaps)
     for s in snaps:
         assert 0.0 <= s.conv_occupancy <= 1.0
