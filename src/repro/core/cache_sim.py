@@ -19,6 +19,8 @@ interleaved streams -> longer reuse distance -> more DRAM traffic).
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, replace
 from typing import Dict, List, Sequence, Tuple
 
@@ -211,18 +213,26 @@ def apply_overrides(cfg: MorpheusConfig,
     return replace(cfg, **kw)
 
 
-def _prepare(pt: RunPoint):
-    """Resolve a point: mode-split overrides, trace generation, config.
-
-    Returns (cfg, trace-tuple-for-engine, resolved n_compute/n_cache,
-    post-warmup access count)."""
+def _resolve(pt: RunPoint) -> Tuple[MorpheusConfig, int, int]:
+    """The cheap half of preparing a point: the §7.1 mode-split override
+    and the config, from which its dispatch group is known without a
+    trace.  Returns (cfg, resolved n_compute, resolved n_cache)."""
     spec = SYSTEMS[pt.system]
-    w = tr.WORKLOADS[pt.app]
     n_compute, n_cache = pt.n_compute, pt.n_cache
-    if not w.memory_bound and spec.morpheus:
+    if not tr.WORKLOADS[pt.app].memory_bound and spec.morpheus:
         n_cache = 0   # §7.1 obs. 5: all cores stay in compute mode
         n_compute = TOTAL_CORES
+    cfg = apply_overrides(build_config(spec, n_cache), pt.overrides)
+    return cfg, n_compute, n_cache
 
+
+def _generate(pt: RunPoint, n_compute: int):
+    """The costly half: the point's trace (the Unified-SM-Mem filter
+    applied) and its post-warm-up access count.  Pure numpy, a function
+    of the point alone, so ``run_batch`` runs it on pool threads.
+
+    Returns (trace-tuple-for-engine, post-warmup access count)."""
+    spec = SYSTEMS[pt.system]
     addrs, writes, levels = tr.generate(pt.app, n_cores=n_compute,
                                         length=pt.length, seed=pt.seed,
                                         ws_scale=1.0 / spec.sim_scale)
@@ -230,13 +240,22 @@ def _prepare(pt: RunPoint):
         addrs, writes, levels = _unified_filter(addrs, writes, levels,
                                                 n_compute,
                                                 spec.unified_extra_bytes)
-    cfg = apply_overrides(build_config(spec, n_cache), pt.overrides)
     # exclude the compulsory-miss warmup (one pass over the working set,
     # capped at half the trace) so stats reflect steady state
-    ws_blocks = w.working_set_bytes // spec.sim_scale // tr.BLOCK_BYTES
+    ws_blocks = (tr.WORKLOADS[pt.app].working_set_bytes // spec.sim_scale
+                 // tr.BLOCK_BYTES)
     warmup = int(min(len(addrs) // 2, ws_blocks))
-    return (cfg, (addrs, writes, levels, warmup), n_compute, n_cache,
-            len(addrs) - warmup)
+    return (addrs, writes, levels, warmup), len(addrs) - warmup
+
+
+def _prepare(pt: RunPoint):
+    """Resolve a point: mode-split overrides, trace generation, config.
+
+    Returns (cfg, trace-tuple-for-engine, resolved n_compute/n_cache,
+    post-warmup access count)."""
+    cfg, n_compute, n_cache = _resolve(pt)
+    trace, n_acc = _generate(pt, n_compute)
+    return cfg, trace, n_compute, n_cache, n_acc
 
 
 def _finalize(pt: RunPoint, n_compute: int, n_cache: int, n_acc: int,
@@ -320,6 +339,91 @@ def _chunk_lengths(n: int) -> List[int]:
     return out
 
 
+# requests per core stream that each prefetch thread needs (PERF.md, the
+# width curve on the TPU v5e host)
+STREAM_PER_THREAD = 8192
+
+
+def _prefetch_width(n_points: int, per_stream: int, cpus: int) -> int:
+    """Threads that make a ``run_batch`` call's traces ahead of its
+    dispatches: ``min(points, cpus, cap)``, at least 1.
+
+    ``per_stream`` is the call's requests per core stream (``length //
+    n_compute``, the median over its points).  It decides how much of
+    ``tr.generate`` runs in numpy with the interpreter lock released.  A
+    short stream spends its time in the per-core Python loop, which holds
+    the lock: one thread then overlaps the calling thread's packing and
+    device waits, and a second only contends with both.  A long stream
+    widens the pool, one thread per ``STREAM_PER_THREAD`` requests, up to
+    the CPUs."""
+    cap = max(1, per_stream // STREAM_PER_THREAD)
+    return max(1, min(n_points, cpus, cap))
+
+
+class _TracePrefetch:
+    """``run_batch``'s traces, made ahead of its dispatches on a pool.
+
+    ``start`` submits every point's ``_generate`` in dispatch order;
+    ``take`` hands over one chunk's traces, blocking until they exist.
+    A single point takes no pool: ``take`` makes its trace.  Closing
+    cancels what has not started, so an error does not wait for the rest
+    of the sweep's traces."""
+
+    def __init__(self, points: Sequence[RunPoint]):
+        self._points = points
+        self._n_compute: List[int] = []
+        self._pool: ThreadPoolExecutor | None = None
+        self._ahead: Dict[int, Future] = {}
+
+    def start(self, resolved, plan) -> int:
+        """Submit the generation of every point; returns the width."""
+        self._n_compute = [n_compute for _, n_compute, _ in resolved]
+        streams = [pt.length // max(n, 1)
+                   for pt, n in zip(self._points, self._n_compute)]
+        width = _prefetch_width(len(streams),
+                                int(np.median(streams)) if streams else 0,
+                                len(os.sched_getaffinity(0)))
+        if len(streams) > 1:
+            self._pool = ThreadPoolExecutor(width)
+            self._ahead = {i: self._pool.submit(_generate, self._points[i],
+                                                self._n_compute[i])
+                           for _, _, chunk, _ in plan for i in chunk}
+        return width
+
+    def take(self, chunk: Sequence[int]) -> list:
+        """(trace, post-warm-up access count) of each point of ``chunk``."""
+        futures = [self._ahead.pop(i, None) for i in chunk]
+        ready = self._pool is not None and all(f.done() for f in futures)
+        obs.count("trace_prefetch", 1, ready="yes" if ready else "no")
+        return [f.result() if f is not None
+                else _generate(self._points[i], self._n_compute[i])
+                for f, i in zip(futures, chunk)]
+
+    def __enter__(self) -> "_TracePrefetch":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._pool is not None:
+            self._pool.shutdown(cancel_futures=True)
+        return False
+
+
+def _dispatch_plan(points: Sequence[RunPoint], resolved) -> list:
+    """The dispatches of a sweep, in order: (cfg, backend, chunk of point
+    indices, compiled batch length) per dispatch, group after group."""
+    groups: Dict[tuple, List[int]] = {}
+    for i, (cfg, _, _) in enumerate(resolved):
+        backend = engine.resolve_backend(points[i].backend or None)
+        groups.setdefault((cfg, backend), []).append(i)
+    plan = []
+    for (cfg, backend), idxs in groups.items():
+        done = 0
+        for blen in _chunk_lengths(len(idxs)):
+            plan.append((cfg, backend, idxs[done:done + blen], blen))
+            done += blen
+    return plan
+
+
 def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
     """Run many grid points through the set-parallel engine, batched.
 
@@ -332,48 +436,61 @@ def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
     policy, the benchmark figures) is built on: larger grids, multi-seed
     error bars and online mode-split search are all one ``run_batch``.
 
-    Spans: ``cache_sim.run_batch`` around the whole call, and its phases
-    ``cache_sim.prepare`` (trace generation and configs), per dispatch
+    Every point is resolved first on the calling thread (``_resolve``:
+    mode split and config), which fixes the groups and chunks.  The
+    traces (``_generate``) are then made ahead on a pool of
+    ``_prefetch_width`` threads, submitted in dispatch order, so that
+    generation overlaps the packing, dispatches and readbacks of the
+    chunks before; the calling thread blocks only on the traces of the
+    chunk it packs next.  The pool threads run numpy alone: no JAX call,
+    no span.  A single point takes no pool: the calling thread makes its
+    trace.  Every result is the same at any width.
+
+    Spans: ``cache_sim.run_batch`` around the whole call (tags ``points``,
+    ``groups`` and ``workers``, the pool's width), and per dispatch its
+    phases ``cache_sim.prepare`` (the calling thread blocked on the
+    chunk's traces; the first one also resolves every point),
     ``engine.pack`` and ``engine.dispatch`` (in ``simulate_batch``),
     ``cache_sim.wait`` (the host blocked on the device) and
     ``cache_sim.unpack`` (one ``jax.device_get`` of the dispatch's Stats,
     then per-point Stats sliced from the host arrays and ``_finalize``).
-    Counter: ``stats_readbacks{path="batch"}``, one per dispatch.
+    Counters, one per dispatch: ``stats_readbacks{path="batch"}``, and
+    ``trace_prefetch{ready}``, ``yes`` where the pool had made every trace
+    of the chunk before the calling thread asked for them.
     """
     results: List[RunResult] = [None] * len(points)  # type: ignore
-    with obs.span("cache_sim.run_batch", points=len(points)) as sp:
+    with obs.span("cache_sim.run_batch", points=len(points)) as sp, \
+            _TracePrefetch(points) as ahead:
         with obs.span("cache_sim.prepare"):
-            prepped = [_prepare(pt) for pt in points]
-        groups: Dict[tuple, List[int]] = {}
-        for i, (cfg, _, _, _, _) in enumerate(prepped):
-            backend = engine.resolve_backend(points[i].backend or None)
-            groups.setdefault((cfg, backend), []).append(i)
-        sp.set(groups=len(groups))
-        for (cfg, backend), idxs in groups.items():
-            done = 0
-            for blen in _chunk_lengths(len(idxs)):
-                chunk = idxs[done:done + blen]
-                done += len(chunk)
-                traces = [prepped[i][1] for i in chunk]
-                while len(traces) < blen:     # pad to the compiled shape
-                    traces.append(traces[-1])
-                stats_b = engine.simulate_batch(cfg, traces, backend)
-                with obs.span("cache_sim.wait"):
-                    jax.block_until_ready(stats_b)
-                with obs.span("cache_sim.unpack"):
-                    # one transfer for the whole (B,) Stats, then rows
-                    # sliced on the host: slicing the device arrays per
-                    # point costs a device op and a sync per field
-                    host = jax.device_get(stats_b)
-                    obs.count("stats_readbacks", 1, path="batch")
-                    if obs.metrics_on():
-                        obs.count("device_get_bytes",
-                                  sum(x.nbytes for x in host))
-                    for j, i in enumerate(chunk):
-                        stats = Stats(*[np.asarray(x[j]) for x in host])
-                        _, _, n_compute, n_cache, n_acc = prepped[i]
-                        results[i] = _finalize(points[i], n_compute, n_cache,
-                                               n_acc, stats)
+            resolved = [_resolve(pt) for pt in points]
+            plan = _dispatch_plan(points, resolved)
+            sp.set(groups=len({(c, b) for c, b, _, _ in plan}),
+                   workers=ahead.start(resolved, plan))
+            made = ahead.take(plan[0][2]) if plan else []
+        for k, (cfg, backend, chunk, blen) in enumerate(plan):
+            traces = [trace for trace, _ in made]
+            while len(traces) < blen:     # pad to the compiled shape
+                traces.append(traces[-1])
+            stats_b = engine.simulate_batch(cfg, traces, backend)
+            with obs.span("cache_sim.wait"):
+                jax.block_until_ready(stats_b)
+            with obs.span("cache_sim.unpack"):
+                # one transfer for the whole (B,) Stats, then rows
+                # sliced on the host: slicing the device arrays per
+                # point costs a device op and a sync per field
+                host = jax.device_get(stats_b)
+                obs.count("stats_readbacks", 1, path="batch")
+                if obs.metrics_on():
+                    obs.count("device_get_bytes",
+                              sum(x.nbytes for x in host))
+                for j, i in enumerate(chunk):
+                    stats = Stats(*[np.asarray(x[j]) for x in host])
+                    _, n_compute, n_cache = resolved[i]
+                    results[i] = _finalize(points[i], n_compute, n_cache,
+                                           made[j][1], stats)
+            if k + 1 < len(plan):
+                with obs.span("cache_sim.prepare"):
+                    made = ahead.take(plan[k + 1][2])
     return results
 
 

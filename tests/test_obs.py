@@ -15,8 +15,9 @@ Headline properties (acceptance):
     (the golden CRC guarantee extends under instrumentation);
   * spans reach the JAX profiler's timeline as disjoint, contiguous
     segments named after the innermost open span (self time), and none
-    are built with tracing off; ``run_batch``'s five phase spans cover
-    the call, and its results are bit-identical with obs on or off;
+    are built with tracing off; ``run_batch``'s five phase spans, one of
+    each per dispatch on the calling thread, cover the call, and its
+    results are bit-identical with obs on or off;
   * ``TelemetryLog`` exports oldest -> newest even after the ring wraps;
   * ``tools/obs_report.py`` renders a bundle.
 """
@@ -219,20 +220,28 @@ def test_tracing_off_builds_no_annotation(monkeypatch):
     assert "engine.pack" in made and "cache_sim.run_batch" in made
 
 
-def test_run_batch_phase_spans_cover_the_call():
+def test_run_batch_phase_spans_cover_the_call(monkeypatch):
+    # a pool of two threads makes the traces, whatever the host's CPUs
+    monkeypatch.setattr(cs, "_prefetch_width", lambda n, s, c: min(n, 2))
     pts = _tiny_points()
     cs.run_batch(pts)                       # compile outside the record
     obs.enable()
     cs.run_batch(pts)
-    dispatches = obs.metrics_registry().get("engine_dispatches").total()
+    reg = obs.metrics_registry()
+    dispatches = reg.get("engine_dispatches").total()
     assert dispatches == 2
+    assert reg.get("trace_prefetch").total() == dispatches
     spans = [e for e in obs.tracer().events if e["ph"] == "X"]
     top, = [e for e in spans if e["name"] == "cache_sim.run_batch"]
     assert top["args"]["points"] == 4 and top["args"]["groups"] == 2
+    assert top["args"]["workers"] == 2
+    # the pool's threads emit no span: every one is the calling thread's
+    assert {e["tid"] for e in spans} == {top["tid"]}
     phases = [e for e in spans if e["name"] in PHASES]
     names = [e["name"] for e in phases]
-    assert names.count("cache_sim.prepare") == 1
-    for name in PHASES[1:]:
+    # one prepare per dispatch: the calling thread blocked on its chunk's
+    # traces (the first one also resolves every point)
+    for name in PHASES:
         assert names.count(name) == dispatches, name
     assert len(phases) == len(spans) - 1
     assert all(e["args"]["parent"] == top["args"]["id"] for e in phases)
