@@ -28,7 +28,8 @@ def run():
     # one batched dispatch set: BL / IBL / Morpheus-ALL / larger-LLC per app
     pts, meta = [], []
     for app in mb:
-        pts.append(cs.RunPoint(app, "BL", cs.TOTAL_CORES, 0, C.TRACE_LEN))
+        pts.append(cs.RunPoint(app, "BL", cs.TABLE1.total_cores, 0,
+                               C.TRACE_LEN))
         meta.append((app, "bl"))
         n_c, n_k = splits["IBL"][app]
         pts.append(cs.RunPoint(app, "IBL", n_c, n_k, C.TRACE_LEN))
@@ -38,8 +39,9 @@ def run():
         meta.append((app, "mall"))
         # larger-LLC: conventional LLC scaled to Morpheus-ALL's total
         # capacity, same bank count (isolates capacity from banking)
-        total_cap = cs.CONV_LLC_BYTES + n_k * cs.EXT_BYTES_PER_CORE
-        scale = total_cap / cs.CONV_LLC_BYTES
+        gpu = cs.TABLE1
+        total_cap = gpu.conv_llc_bytes + n_k * gpu.ext_bytes_per_core
+        scale = total_cap / gpu.conv_llc_bytes
         name = f"_larger{scale:.2f}"
         if name not in cs.SYSTEMS:
             cs.SYSTEMS[name] = replace(cs.SYSTEMS["IBL"], name=name,

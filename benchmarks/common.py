@@ -189,7 +189,7 @@ def mode_splits(systems: Sequence[str], apps: Sequence[str],
             if spec.morpheus and not w.memory_bound:
                 # §7.1 obs. 5: compute-bound apps keep every core in
                 # compute mode (cs.run enforces this; record it directly)
-                sys_cache[app] = [cs.TOTAL_CORES, 0]
+                sys_cache[app] = [cs.TABLE1.total_cores, 0]
                 changed = True
                 continue
             g = mgrid if (spec.morpheus and w.memory_bound) else grid

@@ -30,7 +30,7 @@ def run() -> Dict[str, Dict[str, cs.RunResult]]:
 
     # all 9 systems x 17 apps as one batched dispatch set; run_batch groups
     # the points by config shape (system flags + cache-chip count)
-    pts = [cs.RunPoint(app, "BL", cs.TOTAL_CORES, 0, C.TRACE_LEN)
+    pts = [cs.RunPoint(app, "BL", cs.TABLE1.total_cores, 0, C.TRACE_LEN)
            for app in apps]
     for app in apps:
         for system in SYSTEMS[1:]:

@@ -35,7 +35,8 @@ def run():
     # one batched dispatch set: BL baselines + all 3 predictor variants
     pts, meta = [], []
     for app in tr.MEMORY_BOUND:
-        pts.append(cs.RunPoint(app, "BL", cs.TOTAL_CORES, 0, C.TRACE_LEN))
+        pts.append(cs.RunPoint(app, "BL", cs.TABLE1.total_cores, 0,
+                               C.TRACE_LEN))
         meta.append((app, "BL"))
         n_c, n_k = splits["Morpheus-Basic"][app]
         for name, pred in VARIANTS.items():

@@ -94,7 +94,8 @@ def characterize(app: str, *, length: int, epoch_len: int,
 def run() -> Dict[str, float]:
     apps = _APPS[C.PROFILE]
     length, epoch_len = _LEN[C.PROFILE], _EPOCH[C.PROFILE]
-    conv_bytes = cs.CONV_LLC_BYTES // cs.SYSTEMS[SYSTEM].sim_scale
+    spec = cs.SYSTEMS[SYSTEM]
+    conv_bytes = spec.gpu.conv_llc_bytes // spec.sim_scale
     rows: List[List] = []
     out: Dict[str, float] = {}
     agree: List[bool] = []

@@ -35,7 +35,7 @@ def run():
 
     mb = tr.MEMORY_BOUND
     basic_fewer = sum(splits["Morpheus-Basic"][a][0] <
-                      cs.TOTAL_CORES for a in mb)
+                      cs.TABLE1.total_cores for a in mb)
     C.verdict("tab3.morpheus-frees-cores", basic_fewer >= len(mb) - 2,
               f"Morpheus-Basic uses <68 compute cores for {basic_fewer}/"
               f"{len(mb)} memory-bound apps")
@@ -44,7 +44,7 @@ def run():
     C.verdict("tab3.compression-frees-cache-cores", all_ge >= len(mb) // 2,
               f"Morpheus-ALL compute-cores >= Basic for {all_ge}/{len(mb)} "
               f"apps (paper: ALL uses more compute cores)")
-    cb_all68 = all(splits[s][a][0] == cs.TOTAL_CORES
+    cb_all68 = all(splits[s][a][0] == cs.TABLE1.total_cores
                    for s in SYSTEMS for a in tr.COMPUTE_BOUND)
     C.verdict("tab3.compute-bound-keeps-68", cb_all68,
               "all compute-bound apps keep 68 compute cores")

@@ -28,14 +28,14 @@ def main():
     print(f"app = {args.app} "
           f"({'memory' if tr.WORKLOADS[args.app].memory_bound else 'compute'}"
           f"-bound)\n")
-    base = cs.run(args.app, "BL", n_compute=cs.TOTAL_CORES,
+    base = cs.run(args.app, "BL", n_compute=cs.TABLE1.total_cores,
                   length=args.length)
     print(f"{'system':22s} {'cores':>11s} {'norm time':>9s} "
           f"{'hit rate':>8s} {'MPKI':>7s}")
     rows = {}
     for system in SYSTEMS:
         if system == "BL":
-            r, nc, nk = base, cs.TOTAL_CORES, 0
+            r, nc, nk = base, cs.TABLE1.total_cores, 0
         else:
             split = best_split(args.app, system, length=args.length)
             nc, nk = split.n_compute, split.n_cache

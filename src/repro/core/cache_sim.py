@@ -10,7 +10,7 @@ Execution-time model (standard bottleneck/roofline composition):
     t_compute = insts / (n_compute * IPC_core * f)
     t_bw      = max(dram_bytes/BW_dram, conv_bytes/BW_conv, noc_bytes/BW_noc,
                     ext_bytes/(n_cache * BW_ext_core))
-    t_lat     = sum(request latencies) / MLP,  MLP = n_compute * mlp_per_core
+    t_lat     = sum(request latencies) / MLP,  MLP = n_compute * MLP_PER_CORE
     t_exec    = max(t_compute, t_bw, t_lat)
 
 Memory-bound apps saturate when t_bw/t_lat dominate; the kmeans-style
@@ -34,36 +34,69 @@ from . import traces as tr
 from .controller import MorpheusConfig, Predictor, Stats
 from .energy import PaperGPU
 
-# --- baseline machine constants (RTX 3080-like, Table 1) -------------------
-TOTAL_CORES = 68
-FREQ_GHZ = 1.44
-IPC_PER_CORE = 1.0          # warp-instructions/cycle/SM sustained
-MLP_PER_CORE = 128.0        # outstanding memory requests per SM (48 warps
-#                             x >2 outstanding loads; keeps the latency term
-#                             from masking the bandwidth wall, Fig. 1 knee)
-CONV_LLC_BYTES = 5 * (1 << 20)
 SIM_SCALE = 8               # default ``SystemSpec.sim_scale``: a 1/8-scale
 #                             memory system (capacities and working sets both
 #                             scaled; behaviour of a set-associative LLC is
 #                             ~invariant under this)
+
+
+# --- the time model's constants, shared by every modelled GPU -------------
+IPC_PER_CORE = 1.0          # warp-instructions/cycle/SM sustained
+MLP_PER_CORE = 128.0        # outstanding memory requests per SM (48 warps
+#                             x >2 outstanding loads; keeps the latency term
+#                             from masking the bandwidth wall, Fig. 1 knee)
 CONV_WAYS = 32
-LLC_PARTITIONS = 10
-EXT_BYTES_PER_CORE = 328 * 1024     # §5 'Combining': RF(32w) + L1(16w)
 EXT_WAYS = 32
-EXT_SET_BYTES = EXT_WAYS * tr.BLOCK_BYTES
-EXT_SETS_PER_CORE = EXT_BYTES_PER_CORE // EXT_SET_BYTES     # 82
-BW_DRAM = 760e9
-# Effective (not peak) conventional-LLC bandwidth.  Microbenchmarks measure
-# ~1.2-1.9 TB/s sustained L2 bandwidth on Ampere-class parts under real
-# access mixes (Jia+ [31]); using the 10x300 GB/s per-partition peak would
-# let a 4x-capacity LLC escape memory-boundedness entirely, which
-# contradicts the paper's Fig. 2 (avg 1.57x, not 4x).  This constant also
-# makes Morpheus' extra banks matter, reproducing §7.4's split between
-# capacity and banking gains.
-BW_CONV = LLC_PARTITIONS * 120e9
-BW_NOC = 1.5e12
 BW_EXT_CORE = 34e9          # §5: per cache-mode core
+# §4.3.2: the native Indirect-MOV instruction turns every data-array access
+# from 3 instructions (2 of them branches) into 1, raising the helper
+# kernel's service throughput per cache-mode core
+INDIRECT_MOV_EXT_BW_GAIN = 1.15
 MAX_CACHE_FRAC = 0.75       # §4.1.3: up to 75% of SMs in cache mode
+
+
+@dataclass(frozen=True)
+class GPUSpec:
+    """The modelled GPU: its cores, its LLC and the bandwidths of the
+    analytical time model.  Field names are keys of a benchmark
+    configuration's ``gpu`` block.  Latencies and energies are the paper's
+    (``energy.PaperGPU``), whatever the GPU."""
+    name: str
+    total_cores: int
+    freq_ghz: float
+    conv_llc_bytes: int
+    ext_bytes_per_core: int     # RF + L1/shared a cache-mode core lends
+    bw_dram: float
+    # Effective (not peak) conventional-LLC bandwidth: 0.4 of the L2's
+    # published peak, Table 1's 120 of each partition's 300 GB/s.
+    # Table 1's 1.2 TB/s sits in the ~1.2-1.9 TB/s that microbenchmarks
+    # sustain on its class of part under real access mixes (Jia+ [31]);
+    # using the peak would let a 4x-capacity LLC escape memory-boundedness
+    # entirely, which contradicts the paper's Fig. 2 (avg 1.57x, not 4x).
+    # It also makes Morpheus' extra banks matter, reproducing §7.4's split
+    # between capacity and banking gains.
+    bw_conv: float
+    bw_noc: float               # 1.25 x bw_conv, Table 1's ratio
+
+    @property
+    def ext_sets_per_core(self) -> int:
+        return self.ext_bytes_per_core // (EXT_WAYS * tr.BLOCK_BYTES)
+
+
+# the paper's GPU (RTX 3080-like, Table 1); §5 'Combining': RF (32 warps)
+# + L1 (16 warps), 328 KiB a cache-mode SM, 82 extended sets; L2 of 10
+# partitions at 300 GB/s peak, 120 GB/s effective
+TABLE1 = GPUSpec("Table1", total_cores=68, freq_ghz=1.44,
+                 conv_llc_bytes=5 * (1 << 20), ext_bytes_per_core=328 * 1024,
+                 bw_dram=760e9, bw_conv=10 * 120e9, bw_noc=1.5e12)
+# NVIDIA A100 SXM4 40 GB (A100 whitepaper, 2020): 108 SMs at 1.41 GHz
+# boost, 40 MB L2, 1 555 GB/s HBM2, L2 read bandwidth 5 120 B/clk (7.22
+# TB/s peak at 1.41 GHz; bw_conv 0.4 of it, bw_noc 1.25 x bw_conv).  A
+# cache-mode SM lends Table 1's share (328 of 384 KiB) of its 256 KiB RF +
+# 192 KiB L1/shared, in whole 4 KiB sets: 95.
+A100 = GPUSpec("A100", total_cores=108, freq_ghz=1.41,
+               conv_llc_bytes=40 * (1 << 20), ext_bytes_per_core=95 * 4096,
+               bw_dram=1555e9, bw_conv=2887.68e9, bw_noc=3609.6e9)
 
 
 @dataclass(frozen=True)
@@ -77,6 +110,7 @@ class SystemSpec:
     mem_boost: float = 1.0           # Frequency-Boost: BW*, 1/latency*
     unified_extra_bytes: int = 0     # Unified-SM-Mem: extra per-core filter
     sim_scale: int = SIM_SCALE       # capacities and working sets at 1/scale
+    gpu: GPUSpec = TABLE1
 
 
 SYSTEMS: Dict[str, SystemSpec] = {
@@ -98,14 +132,21 @@ SYSTEMS: Dict[str, SystemSpec] = {
     "Morpheus-ALL@1": SystemSpec("Morpheus-ALL@1", morpheus=True,
                                  compression=True, indirect_mov=True,
                                  sim_scale=1),
+    # the same design on an A100-class GPU at its published capacity:
+    # 10 240 conventional sets, 95 extended sets per cache-mode core
+    # (7 695 at the 75% cap)
+    "Morpheus-ALL@A100": SystemSpec("Morpheus-ALL@A100", morpheus=True,
+                                    compression=True, indirect_mov=True,
+                                    sim_scale=1, gpu=A100),
 }
 
 
 def build_config(spec: SystemSpec, n_cache: int) -> MorpheusConfig:
-    conv_bytes = int(CONV_LLC_BYTES * spec.conv_scale) // spec.sim_scale
+    gpu = spec.gpu
+    conv_bytes = int(gpu.conv_llc_bytes * spec.conv_scale) // spec.sim_scale
     conv_sets = max(conv_bytes // (CONV_WAYS * tr.BLOCK_BYTES), 16)
     n_cache = n_cache if spec.morpheus else 0
-    sets_per_chip = max(EXT_SETS_PER_CORE // spec.sim_scale, 2)
+    sets_per_chip = max(gpu.ext_sets_per_core // spec.sim_scale, 2)
     amap = asep.make_map(conv_sets=conv_sets, num_cache_chips=n_cache,
                          sets_per_chip=sets_per_chip)
     return MorpheusConfig(amap=amap, conv_ways=CONV_WAYS, ext_ways=EXT_WAYS,
@@ -221,7 +262,7 @@ def _resolve(pt: RunPoint) -> Tuple[MorpheusConfig, int, int]:
     n_compute, n_cache = pt.n_compute, pt.n_cache
     if not tr.WORKLOADS[pt.app].memory_bound and spec.morpheus:
         n_cache = 0   # §7.1 obs. 5: all cores stay in compute mode
-        n_compute = TOTAL_CORES
+        n_compute = spec.gpu.total_cores
     cfg = apply_overrides(build_config(spec, n_cache), pt.overrides)
     return cfg, n_compute, n_cache
 
@@ -270,25 +311,24 @@ def _finalize(pt: RunPoint, n_compute: int, n_cache: int, n_acc: int,
     instead of attributing the whole epoch to the dominant app.
     """
     app, spec = pt.app, SYSTEMS[pt.system]
+    gpu = spec.gpu
     w = tr.WORKLOADS[app]
     if insts is None:
         insts = tr.instructions_for(app, n_acc)
     if knee is None:
         knee = w.contention_knee
-    gpu = PaperGPU()
+    costs = PaperGPU()
 
     boost = spec.mem_boost
-    t_compute = insts / (n_compute * IPC_PER_CORE * FREQ_GHZ * 1e9)
+    t_compute = insts / (n_compute * IPC_PER_CORE * gpu.freq_ghz * 1e9)
     # DRAM row-buffer locality: interleaving more streams than the app's
     # knee degrades effective DRAM bandwidth (the Fig. 1 'drop' mechanism)
     row_locality = max(0.2, min(1.0, knee / max(n_compute, 1)))
-    t_dram = float(stats.dram_bytes) / (BW_DRAM * boost * row_locality)
-    t_conv = float(stats.conv_bytes) / (BW_CONV * boost)
-    t_noc = float(stats.noc_bytes) / (BW_NOC * boost)
-    # §4.3.2: the native Indirect-MOV instruction turns every data-array
-    # access from 3 instructions (2 of them branches) into 1, raising the
-    # helper kernel's service throughput per cache-mode core
-    ext_bw = BW_EXT_CORE * (1.15 if spec.indirect_mov else 1.0)
+    t_dram = float(stats.dram_bytes) / (gpu.bw_dram * boost * row_locality)
+    t_conv = float(stats.conv_bytes) / (gpu.bw_conv * boost)
+    t_noc = float(stats.noc_bytes) / (gpu.bw_noc * boost)
+    ext_bw = BW_EXT_CORE * (INDIRECT_MOV_EXT_BW_GAIN if spec.indirect_mov
+                            else 1.0)
     t_ext = (float(stats.noc_bytes) / (max(n_cache, 1) * ext_bw)
              if spec.morpheus and n_cache else 0.0)
     t_lat = float(stats.latency_ns) * 1e-9 / (boost * n_compute * MLP_PER_CORE)
@@ -297,12 +337,12 @@ def _finalize(pt: RunPoint, n_compute: int, n_cache: int, n_acc: int,
     # zero-work slice (a departed/idle tenant's epoch in the QoS
     # runtime): no instructions and no traffic means no time — report
     # zero IPC instead of 0/0
-    ipc = insts / (t_exec * FREQ_GHZ * 1e9) if t_exec > 0 else 0.0
+    ipc = insts / (t_exec * gpu.freq_ghz * 1e9) if t_exec > 0 else 0.0
 
     mem_energy_J = float(stats.energy_nJ) * 1e-9
-    power = gpu.static_power_W + gpu.core_power_W * (n_compute + n_cache)
+    power = costs.static_power_W + costs.core_power_W * (n_compute + n_cache)
     if spec.morpheus:
-        power *= 1.0 + gpu.controller_power_frac
+        power *= 1.0 + costs.controller_power_frac
     power += mem_energy_J / max(t_exec, 1e-12)
     energy_J = power * t_exec
     ppw = ipc / power
@@ -447,7 +487,8 @@ def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
     trace.  Every result is the same at any width.
 
     Spans: ``cache_sim.run_batch`` around the whole call (tags ``points``,
-    ``groups`` and ``workers``, the pool's width), and per dispatch its
+    ``groups``, ``workers``, the pool's width, and ``gpu``, the modelled
+    GPUs of its systems by ``GPUSpec.name``), and per dispatch its
     phases ``cache_sim.prepare`` (the calling thread blocked on the
     chunk's traces; the first one also resolves every point),
     ``engine.pack`` and ``engine.dispatch`` (in ``simulate_batch``),
@@ -459,7 +500,9 @@ def run_batch(points: Sequence[RunPoint]) -> List[RunResult]:
     of the chunk before the calling thread asked for them.
     """
     results: List[RunResult] = [None] * len(points)  # type: ignore
-    with obs.span("cache_sim.run_batch", points=len(points)) as sp, \
+    gpus = sorted({SYSTEMS[pt.system].gpu.name for pt in points})
+    with obs.span("cache_sim.run_batch", points=len(points),
+                  gpu=",".join(gpus)) as sp, \
             _TracePrefetch(points) as ahead:
         with obs.span("cache_sim.prepare"):
             resolved = [_resolve(pt) for pt in points]

@@ -509,11 +509,19 @@ def simulate_batch(cfg: MorpheusConfig,
     trace order.  All traces share the compiled executable; distinct
     configs (different set counts / flags) compile separately.  ``backend``
     picks the inner-scan implementation (None -> ``default_backend()``).
+
+    Counter ``device_put_bytes{tier="conv"|"ext"}``: the bytes of each
+    tier's packed batch arrays the dispatch hands the device.
     """
     obs.count("engine_dispatches", 1, path="batch")
     backend = resolve_backend(backend)
     pt = pack(cfg, traces)
     _count_set_tiles(cfg, pt, backend)
+    if obs.metrics_on():
+        for tier in ("conv", "ext"):
+            obs.count("device_put_bytes",
+                      sum(a.nbytes for f, a in zip(pt._fields, pt)
+                          if f.startswith(tier + "_")), tier=tier)
     # host side of the dispatch: argument transfer and the launch
     with obs.span("engine.dispatch"):
         return _run_packed(cfg, pt, backend)

@@ -44,8 +44,8 @@ def grid_points(app: str, system: str, *, grid: Sequence[int],
     for n_compute in grid:
         n_cache = 0
         if spec.morpheus and w.memory_bound:
-            n_cache = min(cs.TOTAL_CORES - n_compute,
-                          int(cs.TOTAL_CORES * cs.MAX_CACHE_FRAC))
+            n_cache = min(spec.gpu.total_cores - n_compute,
+                          int(spec.gpu.total_cores * cs.MAX_CACHE_FRAC))
             if n_cache <= 0:
                 continue
         pts.append(cs.RunPoint(app, system, n_compute, n_cache, length,

@@ -507,7 +507,7 @@ def simulate_overload(tenants: Sequence[TenantSLO],
         if obs.metrics_on():
             obs.set_gauge("fairness_jain", fair, replica="overload")
         occ, acc, _ = _epoch_telemetry(cfg, state, delta)
-        t_comp = insts / (nc * cs.IPC_PER_CORE * cs.FREQ_GHZ * 1e9)
+        t_comp = insts / (nc * cs.IPC_PER_CORE * spec.gpu.freq_ghz * 1e9)
         if t_comp >= 0.99 * rr.exec_time_s:
             hint = +1
         elif occ > 0.9:

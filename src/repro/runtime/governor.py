@@ -715,8 +715,9 @@ def candidates_for(app: str, system: str, *,
     phases have somewhere to go), ordered by compute-core count."""
     pts = policy.grid_points(app, system, grid=grid, length=length)
     splits = [(p.n_compute, p.n_cache) for p in pts]
-    if cs.SYSTEMS[system].morpheus and (cs.TOTAL_CORES, 0) not in splits:
-        splits.append((cs.TOTAL_CORES, 0))
+    spec = cs.SYSTEMS[system]
+    if spec.morpheus and (spec.gpu.total_cores, 0) not in splits:
+        splits.append((spec.gpu.total_cores, 0))
     return sorted(set(splits))
 
 
@@ -1158,7 +1159,8 @@ class OnlineReplica:
         # counters in a real system; the roofline terms here).  Compute-
         # bound => more compute cores can help (+1); a full extended
         # tier on a memory-bound epoch => more cache capacity (-1).
-        t_comp = insts / (nc * cs.IPC_PER_CORE * cs.FREQ_GHZ * 1e9)
+        t_comp = insts / (nc * cs.IPC_PER_CORE * self.spec.gpu.freq_ghz
+                          * 1e9)
         if t_comp >= 0.99 * rr.exec_time_s:
             hint = +1
         elif occ > 0.9:
@@ -1247,7 +1249,7 @@ class OnlineReplica:
     def result(self) -> OnlineResult:
         """Aggregate the finished run (callable once ``done``)."""
         gov, records, workload = self.gov, self.records, self.workload
-        freq = cs.FREQ_GHZ * 1e9
+        freq = self.spec.gpu.freq_ghz * 1e9
         ipc = self.insts_all / (self.t_all * freq) if self.t_all > 0 \
             else 0.0
         steady = self.insts_steady / (self.t_steady * freq) \

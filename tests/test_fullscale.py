@@ -214,8 +214,13 @@ def _assert_pinned(stats, pinned, path):
 
 
 def test_only_the_full_scale_system_is_new():
-    assert set(cs.SYSTEMS) == set(PINNED) | {"Morpheus-ALL@1"}
+    assert set(cs.SYSTEMS) == set(PINNED) | {"Morpheus-ALL@1",
+                                             "Morpheus-ALL@A100"}
     assert cs.SYSTEMS["Morpheus-ALL@1"].sim_scale == 1
+    assert cs.SYSTEMS["Morpheus-ALL@A100"].sim_scale == 1
+    # every system but the A100 one models the Table-1 GPU
+    assert {n for n, s in cs.SYSTEMS.items() if s.gpu != cs.TABLE1} == \
+        {"Morpheus-ALL@A100"}
 
 
 # the jnp engine's cases are named by the system alone

@@ -252,6 +252,40 @@ def test_run_batch_phase_spans_cover_the_call(monkeypatch):
     assert sum(e["dur"] for e in phases) >= 0.9 * top["dur"]
 
 
+def test_run_batch_tags_the_modelled_gpu():
+    obs.enable()
+    cs.run_batch(_tiny_points())
+    top, = [e for e in obs.tracer().events
+            if e["ph"] == "X" and e["name"] == "cache_sim.run_batch"]
+    assert top["args"]["gpu"] == cs.TABLE1.name == "Table1"
+
+
+def test_device_put_bytes_equal_packed_nbytes(monkeypatch):
+    """``device_put_bytes{tier}``: per dispatch, the bytes of the tier's
+    packed batch arrays, padding included."""
+    packed = []
+
+    def keep(cfg, traces, *a, **kw):
+        packed.append(pack(cfg, traces, *a, **kw))
+        return packed[-1]
+    pack = engine.pack
+    monkeypatch.setattr(engine, "pack", keep)
+    obs.enable(trace=False)
+    cs.run_batch(_tiny_points())
+    assert len(packed) == 2
+    reg = obs.metrics_registry()
+    for tier, fields in (("conv", ("conv_tag", "conv_write", "conv_pos",
+                                   "conv_active")),
+                         ("ext", ("ext_tag", "ext_write", "ext_level",
+                                  "ext_pos", "ext_active"))):
+        want = sum(getattr(pt, f).nbytes for pt in packed for f in fields)
+        got = sum(s["value"] for s in reg.get("device_put_bytes").samples()
+                  if s["labels"] == {"tier": tier})
+        assert got == want, tier
+    # the BL dispatch has no extended tier: nothing of it is transferred
+    assert packed[0].ext_tag.size == 0
+
+
 def test_packed_slots_counts_padded_slots():
     obs.enable(trace=False)
     cfg, trace, *_ = cs._prepare(cs.RunPoint("cfd", "Morpheus-ALL", 32, 24,

@@ -57,7 +57,8 @@ def test_policy_mode_split_sane():
     """The Table-3 analogue: memory-bound apps give cores to the cache
     tier; the chosen split must beat the all-compute baseline."""
     split = best_split("kmeans", "Morpheus-ALL", length=16_000)
-    assert 0 < split.n_cache <= int(cs.TOTAL_CORES * cs.MAX_CACHE_FRAC)
-    assert split.n_compute + split.n_cache <= cs.TOTAL_CORES
-    bl = cs.run("kmeans", "BL", n_compute=cs.TOTAL_CORES, length=16_000)
+    gpu = cs.SYSTEMS["Morpheus-ALL"].gpu
+    assert 0 < split.n_cache <= int(gpu.total_cores * cs.MAX_CACHE_FRAC)
+    assert split.n_compute + split.n_cache <= gpu.total_cores
+    bl = cs.run("kmeans", "BL", n_compute=gpu.total_cores, length=16_000)
     assert split.exec_time_s < bl.exec_time_s

@@ -67,13 +67,15 @@ def _assert_kernel(fn, *avals):
 
 # --------------------------------------------------------- engine scan
 
-# the full benchmark profile's trace length on three systems: Morpheus-ALL
+# the full benchmark profile's trace length on four systems: Morpheus-ALL
 # (both tiers, compressed extended ways), the conventional-only baseline,
-# and Morpheus-ALL at full scale (4 182 extended sets: the set-tiled scan
-# within the default scoped VMEM)
+# Morpheus-ALL at full scale (4 182 extended sets: the set-tiled scan
+# within the default scoped VMEM) and on the A100-class GPU (10 240
+# conventional sets in 8 tiles, 7 695 extended sets in 16)
 ENGINE_CELLS = {"morpheus_all": ("kmeans", "Morpheus-ALL", 32, 36),
                 "conv_only": ("kmeans", "BL", 32, 0),
-                "full_scale": ("kmeans", "Morpheus-ALL@1", 17, 51)}
+                "full_scale": ("kmeans", "Morpheus-ALL@1", 17, 51),
+                "a100": ("kmeans", "Morpheus-ALL@A100", 27, 81)}
 
 
 def _packed(cell):
